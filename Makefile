@@ -100,4 +100,4 @@ smoke:
 		--pool-graphs 4 --queries 32 --r 2,2 --s 3,4
 	JAX_PLATFORMS=cpu PYTHONPATH=src $(SMOKE_W) timeout 900 \
 		python -m repro.launch.serve --arch nucleus --server --selftest \
-		--cache-dir /tmp/nucleus-smoke-cache
+		--cache-dir .jax_cache/server-manifest

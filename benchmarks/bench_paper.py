@@ -601,22 +601,29 @@ def server_lane(quick=False) -> list[str]:
     caches are the measurand) and coalesced-batch throughput through the
     Frontend vs one-at-a-time routing."""
     import os
-    import tempfile
+    import shutil
     import time as _time
 
     from repro.core import build_problem
     from repro.graph import generators
     from repro.serve import Frontend, Request, Router
+    from repro.serve.cache import init_persistent_cache
     from .serve_child import run_serve_child
 
     rows = []
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
     # -- restart warm path: cold process vs pre-warmed restart ------------
-    with tempfile.TemporaryDirectory(prefix="nucleus-bench-cache-") as cd:
-        cold = run_serve_child(root, "cold", cache_dir="")
-        run_serve_child(root, "seed", cache_dir=cd)  # the "previous run"
-        warm = run_serve_child(root, "warm", cache_dir=cd)
+    # a fixed subdirectory of this process's compile cache, cleared here so
+    # the cold child starts empty (the cache key includes the path, so it
+    # must not be a temporary name)
+    lane_dir = os.path.join(init_persistent_cache(), "bench_server")
+    shutil.rmtree(lane_dir, ignore_errors=True)
+    cold = run_serve_child(root, "cold", os.path.join(lane_dir, "cold"))
+    warm_cache = os.path.join(lane_dir, "warm")
+    manifest = os.path.join(lane_dir, "manifest")
+    run_serve_child(root, "seed", warm_cache, manifest)  # "previous run"
+    warm = run_serve_child(root, "warm", warm_cache, manifest)
     speedup = cold["wall_s"] / max(warm["wall_s"], 1e-9)
     rows.append(row(
         "server/restart_cold_first_decompose", cold["wall_s"],

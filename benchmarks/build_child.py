@@ -24,7 +24,6 @@ from __future__ import annotations
 import argparse
 import hashlib
 import json
-import os
 import subprocess
 import sys
 import time
@@ -40,10 +39,8 @@ def run_build_child(root: str, graph: str, r: int, s: int, build: str,
 
     The one launcher shared by the `build` bench lane and the
     `make bench-build` CI gate (tools/check_build_budget.py)."""
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    env["PYTHONPATH"] = os.path.join(root, "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    from benchmarks.common import child_env
+    env = child_env(root, "build")
     cmd = [sys.executable, "-m", "benchmarks.build_child", "--graph", graph,
            "--r", str(r), "--s", str(s), "--build", build]
     if budget is not None:

@@ -7,8 +7,9 @@ scale.  Every benchmark prints `name,us_per_call,derived` CSV rows.
 """
 from __future__ import annotations
 
+import os
 import time
-from typing import Callable, Dict
+from typing import Callable, Dict, Optional
 
 import numpy as np
 
@@ -53,3 +54,26 @@ def timed(fn: Callable, repeats: int = 1, warmup: int = 0):
 
 def row(name: str, seconds: float, derived: str = "") -> str:
     return f"{name},{seconds * 1e6:.0f},{derived}"
+
+
+def child_env(root: str, lane: str,
+              extra: Optional[Dict[str, str]] = None) -> Dict[str, str]:
+    """Environment for a lane cell that runs in a child process.
+
+    Such cells measure host-side quantities (peak RSS, restart compile)
+    on the CPU.  A chip belongs to one process at a time, so a parent
+    that holds it cannot hand it to a child, and a child must not fall
+    back to the CPU on an accelerator host without being asked to.  The
+    child therefore runs only where this process was pinned to the CPU
+    with ``JAX_PLATFORMS=cpu``; anything else fails the lane here."""
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        raise RuntimeError(
+            f"lane {lane!r} runs its cells in child processes on the CPU; "
+            f"run it with JAX_PLATFORMS=cpu (JAX_PLATFORMS="
+            f"{os.environ.get('JAX_PLATFORMS')!r} here: a child cannot "
+            f"share an accelerator with the process that holds it)")
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(root, "src") + (
+        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    env.update(extra or {})
+    return env

@@ -41,13 +41,10 @@ def run_distbuild_child(root: str, graph: str, r: int, s: int, shards: int,
                         timeout: int = 1800) -> dict:
     """Launch this module in a fresh subprocess (with ``shards`` forced
     host devices) and parse its JSON record."""
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    flags = env.get("XLA_FLAGS", "")
-    env["XLA_FLAGS"] = (
-        f"{flags} --xla_force_host_platform_device_count={shards}").strip()
-    env["PYTHONPATH"] = os.path.join(root, "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    from benchmarks.common import child_env
+    flags = os.environ.get("XLA_FLAGS", "")
+    env = child_env(root, "distbuild", {"XLA_FLAGS": (
+        f"{flags} --xla_force_host_platform_device_count={shards}").strip()})
     cmd = [sys.executable, "-m", "benchmarks.distbuild_child",
            "--graph", graph, "--r", str(r), "--s", str(s),
            "--shards", str(shards), "--mode", mode]

@@ -25,7 +25,11 @@ subprocess per cell, plus coalesced-batch throughput through the
 `Frontend`).  Compile time is excluded via a warmup call — except in the
 `session`, `stream`, and `server` lanes, where per-shape compile time IS
 (part of) the measurand — so the rows measure steady-state wall-clock
-(what EXPERIMENTS.md records).
+(what EXPERIMENTS.md records).  A lane that raises is recorded as a
+`<lane>/ERROR` row and the driver exits non-zero after the remaining lanes.
+Lanes that run cells in child processes (`build`, `distbuild`, `server`)
+need `JAX_PLATFORMS=cpu`: a chip belongs to one process, so they fail with
+a message rather than fight this process for it or fall back to the CPU.
 """
 from __future__ import annotations
 
@@ -68,7 +72,9 @@ def main() -> None:
     # backend (honors JAX_PLATFORMS etc.; --platform-style overrides
     # belong to the entrypoints, the bench driver just pins the timing)
     from repro.launch.platform import setup_platform
+    from repro.serve.cache import init_persistent_cache
     setup_platform()
+    init_persistent_cache()
 
     from . import bench_paper
     if args.list:
@@ -79,6 +85,7 @@ def main() -> None:
     only = set(filter(None, args.only.split(",")))
     meta = _device_meta()
     collected = []
+    failed = []
     print("name,us_per_call,derived")
     print(f"# device: platform={meta['platform']} "
           f"kind={meta['device_kind']!r} count={meta['device_count']}",
@@ -90,7 +97,8 @@ def main() -> None:
             for r in fn(quick=args.quick):
                 print(r, flush=True)
                 collected.append({**_parse_row(r), **meta})
-        except Exception as e:  # keep the suite running
+        except Exception as e:  # keep the suite running; fail at the end
+            failed.append(name)
             print(f"{name}/ERROR,0,{e!r}", flush=True)
             collected.append({"name": f"{name}/ERROR", "us_per_call": 0.0,
                               "derived": repr(e), **meta})
@@ -115,6 +123,8 @@ def main() -> None:
         if rows:
             print("\n# Roofline (single-pod 16x16, per chip):")
             print(roofline.format_table(rows))
+    if failed:
+        sys.exit(f"benchmark lanes failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

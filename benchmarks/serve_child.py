@@ -5,11 +5,11 @@ whole point — and times the server's first decompose on a PREBUILT
 problem (the Session-lane convention: the build stage has its own lane,
 the timer isolates what the warm path saves — compile + execute):
 
-  cold   no persistent cache: a from-scratch server process, the
-         baseline the restart claim is measured against.
-  seed   persistent compilation cache enabled: the same cold first
-         decompose, but compiles land in --cache-dir and the router's
-         session manifest is saved on exit — the "previous server run".
+  cold   an empty persistent cache: a from-scratch server process,
+         the baseline the restart claim is measured against.
+  seed   the same cold first decompose, but into the cache the warm
+         child reads, and the router's session manifest is saved to
+         --cache-dir on exit — the "previous server run".
   warm   persistent cache + manifest: a restarted server.  The pools
          are pre-warmed from the manifest (all-ghost problems replay
          the exact jit keys, so XLA loads compiles from disk instead of
@@ -28,18 +28,18 @@ import sys
 import time
 
 
-def run_serve_child(root: str, mode: str, cache_dir: str,
-                    r: int = 2, s: int = 3,
+def run_serve_child(root: str, mode: str, xla_cache_dir: str,
+                    manifest_dir: str = "", r: int = 2, s: int = 3,
                     timeout: int = 1200) -> dict:
-    """Launch this module in a fresh subprocess and parse its JSON record."""
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    env["PYTHONPATH"] = os.path.join(root, "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    """Launch this module in a fresh subprocess whose persistent
+    compilation cache is ``xla_cache_dir``; parse its JSON record."""
+    from benchmarks.common import child_env
+    env = child_env(root, "server",
+                    {"JAX_COMPILATION_CACHE_DIR": xla_cache_dir})
     cmd = [sys.executable, "-m", "benchmarks.serve_child", "--mode", mode,
            "--r", str(r), "--s", str(s)]
-    if cache_dir:
-        cmd += ["--cache-dir", cache_dir]
+    if manifest_dir:
+        cmd += ["--cache-dir", manifest_dir]
     out = subprocess.run(cmd, cwd=root, env=env, capture_output=True,
                          text=True, check=True, timeout=timeout)
     return json.loads(out.stdout.strip().splitlines()[-1])
@@ -50,7 +50,7 @@ def main() -> None:
     ap.add_argument("--mode", required=True,
                     choices=["cold", "seed", "warm"])
     ap.add_argument("--cache-dir", default="",
-                    help="persistent cache + manifest dir (seed/warm)")
+                    help="session manifest dir (seed/warm)")
     ap.add_argument("--r", type=int, default=2)
     ap.add_argument("--s", type=int, default=3)
     args = ap.parse_args()
@@ -67,10 +67,11 @@ def main() -> None:
     g = generators.planted_cliques(120, [10, 8, 6], 0.03, seed=3)
     problem = build_problem(g, args.r, args.s)
 
+    init_persistent_cache()
     router = Router()
     prewarm_s, prewarmed = 0.0, 0
     if args.cache_dir:
-        init_persistent_cache(args.cache_dir)
+        os.makedirs(args.cache_dir, exist_ok=True)
     if args.mode == "warm":
         manifest = load_manifest(args.cache_dir)
         if manifest is None:

@@ -660,7 +660,8 @@ def resolve_problem(graph_or_problem,
 
 def plan_config(problem: NucleusProblem,
                 config: NucleusConfig) -> Tuple[NucleusConfig, Plan]:
-    """Resolve ``backend='auto'``/``hierarchy='auto'`` against ``problem``.
+    """Resolve ``backend='auto'``/``hierarchy='auto'`` against ``problem``,
+    and on the dense backend ``use_pallas=None`` to the plan's kernel.
 
     Returns the concrete, re-validated config plus the ``Plan`` decision
     record (explicit configs get a trivial plan).  Shared by
@@ -683,6 +684,9 @@ def plan_config(problem: NucleusProblem,
     if (plan.backend, plan.hierarchy) != (config.backend, config.hierarchy):
         config = dataclasses.replace(config, backend=plan.backend,
                                      hierarchy=plan.hierarchy)
+    if plan.use_pallas is not None and config.use_pallas != plan.use_pallas:
+        # the engine runs the kernel the plan names, not a second guess
+        config = dataclasses.replace(config, use_pallas=plan.use_pallas)
     config.validate()
     return config, plan
 

@@ -277,6 +277,11 @@ class Plan:
     requested_backend: str
     requested_hierarchy: str
     reasons: Tuple[str, ...] = ()
+    # the dense engine's decrement kernel, resolved once here (True:
+    # Pallas scatter plan, False: XLA scatter-add); None off the dense
+    # backend.  ``api.plan_config`` hands it to the engine as
+    # ``config.use_pallas``, so the report and the run cannot disagree.
+    use_pallas: Optional[bool] = None
 
     @property
     def was_auto(self) -> bool:
@@ -295,7 +300,8 @@ class Plan:
         return {"backend": self.backend, "hierarchy": self.hierarchy,
                 "requested_backend": self.requested_backend,
                 "requested_hierarchy": self.requested_hierarchy,
-                "reasons": list(self.reasons)}
+                "reasons": list(self.reasons),
+                "use_pallas": self.use_pallas}
 
     @classmethod
     def from_dict(cls, d: Dict[str, Any]) -> "Plan":
@@ -309,7 +315,8 @@ class Plan:
         return cls(backend=d["backend"], hierarchy=d["hierarchy"],
                    requested_backend=d["requested_backend"],
                    requested_hierarchy=d["requested_hierarchy"],
-                   reasons=tuple(d.get("reasons", ())))
+                   reasons=tuple(d.get("reasons", ())),
+                   use_pallas=d.get("use_pallas"))
 
 
 def candidate_backends(config) -> List[Backend]:
@@ -332,6 +339,7 @@ def candidate_backends(config) -> List[Backend]:
 
 
 def resolve_plan(config, *, n_r: int, n_s: int, n_sub: int,
+                 platform: Optional[str] = None,
                  device_kind: Optional[str] = None,
                  n_devices: Optional[int] = None,
                  r: Optional[int] = None, s: Optional[int] = None,
@@ -342,7 +350,9 @@ def resolve_plan(config, *, n_r: int, n_s: int, n_sub: int,
 
     Problem facts come in as plain ints so the rules are unit-testable;
     ``decompose()``/``Session`` pass them from the built problem.  Device
-    facts default to this process's jax runtime.  The rules (priority
+    facts (``platform`` as ``jax.default_backend()`` names it,
+    ``device_kind`` as ``jax.devices()[0].device_kind`` does, e.g. "TPU v5
+    lite") default to this process's jax runtime.  The rules (priority
     order, DESIGN.md §8):
 
       1. an explicit backend is kept as-is;
@@ -380,18 +390,19 @@ def resolve_plan(config, *, n_r: int, n_s: int, n_sub: int,
         check_capabilities(config)          # raises with the derived message
         raise ConfigError("no capability-compatible backend")  # unreachable
     cand_names = [b.name for b in cands]
+    if platform is None or device_kind is None or n_devices is None:
+        import jax
+        platform = platform or jax.default_backend()
+        device_kind = device_kind or jax.devices()[0].device_kind
+        n_devices = n_devices if n_devices is not None \
+            else len(jax.devices())
 
     if config.backend != AUTO:
         backend = config.backend
         reasons.append(f"backend {backend!r}: explicitly configured")
     else:
-        if device_kind is None or n_devices is None:
-            import jax
-            device_kind = device_kind or jax.default_backend()
-            n_devices = n_devices if n_devices is not None \
-                else len(jax.devices())
         prof = planner_profile.thresholds(device_kind=device_kind,
-                                          platform=device_kind,
+                                          platform=platform,
                                           path=profile_path)
         tiny_nr = prof["tiny_nr"]
         shard_min = prof["shard_min_incidence"]
@@ -416,7 +427,7 @@ def resolve_plan(config, *, n_r: int, n_s: int, n_sub: int,
                            "compress=True implies the sharded collective")
         if backend is None and config.use_pallas:
             backend = pick("dense", "use_pallas=True selects the dense "
-                                    "engine's Pallas round megakernel")
+                                    "engine's Pallas scatter plan")
         if backend is None and n_devices > 1 and build == "sharded":
             backend = pick(
                 "sharded",
@@ -443,7 +454,7 @@ def resolve_plan(config, *, n_r: int, n_s: int, n_sub: int,
                 f"dense per-round working set ~{dense_round_bytes} B "
                 f"exceeds memory_budget_bytes={budget}; the gather "
                 f"backend touches only incident s-cliques per round")
-        if backend is None and device_kind != "cpu":
+        if backend is None and platform != "cpu":
             backend = pick("dense", f"accelerator ({device_kind}): the "
                                     f"compiled engine is the fast path")
         if backend is None and n_r < tiny_nr:
@@ -463,6 +474,22 @@ def resolve_plan(config, *, n_r: int, n_s: int, n_sub: int,
                 f"candidate (preferred picks excluded by the requested "
                 f"method/hierarchy/knobs)")
 
+    pallas = None
+    if backend == "dense":
+        if config.use_pallas is None:
+            pallas = planner_profile.use_pallas_default(
+                platform, device_kind, path=profile_path)
+            why = "use_pallas=None resolved for this device"
+        else:
+            pallas, why = config.use_pallas, "explicitly configured"
+        if pallas and n_s == 0:
+            reasons.append(f"kernel: none, no s-cliques to scatter ({why})")
+        elif pallas:
+            reasons.append(
+                f"kernel: Pallas scatter-only plan (sorted segment sum), "
+                f"interpret={platform == 'cpu'} ({why})")
+        else:
+            reasons.append(f"kernel: XLA scatter-add ({why})")
     caps = get(backend).capabilities
     if (r, s) == (1, 2) and "kcore" in caps.fast_lanes:
         reasons.append(
@@ -488,7 +515,7 @@ def resolve_plan(config, *, n_r: int, n_s: int, n_sub: int,
     return Plan(backend=backend, hierarchy=hierarchy,
                 requested_backend=config.backend,
                 requested_hierarchy=config.hierarchy,
-                reasons=tuple(reasons))
+                reasons=tuple(reasons), use_pallas=pallas)
 
 
 # ---------------------------------------------------------------------------

@@ -30,23 +30,10 @@ from .engine import run_peel_engine
 from .incidence import NucleusProblem
 from .schedule import PeelSchedule
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """jax.shard_map moved out of experimental after 0.4.x; support both."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map
-    # the engine's while_loop carries per-shard state (alive/residual), which
-    # the legacy replication checker cannot type — the modern VMA tracker
-    # handles it via pvary, so only disable checking on the legacy path
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
 
-
-def _pvary(x, axis_names):
-    """Mark x device-varying for shard_map VMA tracking (no-op pre-VMA)."""
-    fn = getattr(jax.lax, "pvary", None)
-    return fn(x, axis_names) if fn is not None else x
+def _varying(x, axis_names):
+    """Mark x device-varying for shard_map's varying-manual-axes typing."""
+    return jax.lax.pcast(x, axis_names, to="varying")
 
 
 def pad_incidence(inc_rid: jnp.ndarray, n_shards: int):
@@ -60,6 +47,27 @@ def pad_incidence(inc_rid: jnp.ndarray, n_shards: int):
         ghost = jnp.full((pad, C), -1, INT)
         inc_rid = jnp.concatenate([inc_rid, ghost], axis=0)
     return inc_rid, n_s + pad
+
+
+def sharded_incidence(problem: NucleusProblem, mesh: Mesh) -> jnp.ndarray:
+    """The problem's incidence padded to a multiple of the mesh's devices
+    and laid out as the sharded peel reads it: contiguous s-clique slabs,
+    one per device (axis 0 over every mesh axis).
+
+    Memoized on the problem per mesh, like the dense engine's scatter
+    plans: repeated sharded runs skip the pad and the host-to-device
+    copy, and a caller can inspect the very array the run used
+    (``addressable_shards``)."""
+    cache = getattr(problem, "_sharded_inc", None)
+    if cache is None:
+        cache = {}
+        problem._sharded_inc = cache
+    if mesh not in cache:
+        n_dev = int(np.prod(mesh.devices.shape))
+        inc, _ = pad_incidence(problem.inc_rid, n_dev)
+        cache[mesh] = jax.device_put(
+            inc, NamedSharding(mesh, P(tuple(mesh.axis_names))))
+    return cache[mesh]
 
 
 def make_sharded_decomposition(mesh: Mesh, n_r: int, n_s_padded: int, C: int,
@@ -146,13 +154,13 @@ def make_sharded_decomposition(mesh: Mesh, n_r: int, n_s_padded: int, C: int,
         # alive/residual are per-shard state: mark them device-varying so
         # the engine's while_loop carry types match (shard_map VMA tracking)
         n_s_local = inc_local.shape[0]
-        alive0 = _pvary(jnp.ones((n_s_local,), bool), axis_names)
-        resid0 = _pvary(
+        alive0 = _varying(jnp.ones((n_s_local,), bool), axis_names)
+        resid0 = _varying(
             jnp.zeros((n_r,) if compress else (1,), INT), axis_names)
         if hierarchy:
-            link0 = (_pvary(jnp.arange(n_r, dtype=INT), axis_names),
-                     _pvary(jnp.full((n_r,), -1, INT), axis_names),
-                     _pvary(jnp.full((n_s_local,), -1, INT), axis_names))
+            link0 = (_varying(jnp.arange(n_r, dtype=INT), axis_names),
+                     _varying(jnp.full((n_r,), -1, INT), axis_names),
+                     _varying(jnp.full((n_s_local,), -1, INT), axis_names))
             core, _order, rounds, parent, L = run_peel_engine(
                 inc_local, deg0, schedule, max_rounds=cap_rounds,
                 reduce_delta=reduce_delta, resid0=resid0, alive0=alive0,
@@ -172,9 +180,9 @@ def make_sharded_decomposition(mesh: Mesh, n_r: int, n_s_padded: int, C: int,
         body = lambda inc_local, deg0: local_fn(inc_local, deg0)
     else:
         body = local_fn
-    fn = _shard_map(body, mesh=mesh,
-                    in_specs=(shard_spec,) + (repl_spec,) * (n_in - 1),
-                    out_specs=(repl_spec,) * n_out)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(shard_spec,) + (repl_spec,) * (n_in - 1),
+                       out_specs=(repl_spec,) * n_out)
     in_sh = (NamedSharding(mesh, shard_spec),) + \
         (NamedSharding(mesh, repl_spec),) * (n_in - 1)
     out_sh = (NamedSharding(mesh, repl_spec),) * n_out
@@ -233,12 +241,12 @@ def sharded_decomposition(problem: NucleusProblem, mesh: Mesh,
     built over: ``link_state_from_forest(peel_value, parent, L)`` is the
     tree-building input, NOT the clipped approx estimates in ``core``.
     """
-    n_dev = int(np.prod(mesh.devices.shape))
-    inc, n_s_pad = pad_incidence(problem.inc_rid, n_dev)
+    inc = sharded_incidence(problem, mesh)
     schedule = PeelSchedule(kind=kind, s_choose_r=comb(problem.s, problem.r),
                             delta=delta, n=problem.g.n)
-    fn = _jitted_decomposition(mesh, problem.n_r, n_s_pad, problem.n_sub,
-                               schedule, max_rounds, compress, hierarchy)
+    fn = _jitted_decomposition(mesh, problem.n_r, int(inc.shape[0]),
+                               problem.n_sub, schedule, max_rounds,
+                               compress, hierarchy)
     out = fn(inc, problem.deg0)
     core, rounds = out[0], out[1]
     raw = core

@@ -51,6 +51,7 @@ from ..kernels.peel_round import (chunk_windows, fused_peel_round,
                                   peel_round_plan)
 from ..kernels.segment_sum import (DEFAULT_BLOCK_N, DEFAULT_CHUNK_E,
                                    segment_sum_sorted, sorted_ids_plan)
+from .backends import ConfigError
 from .incidence import NucleusProblem
 from .schedule import PeelSchedule
 
@@ -66,19 +67,13 @@ def make_schedule(problem: NucleusProblem, kind: str,
 def pallas_by_default() -> bool:
     """THE default-kernel policy: what ``use_pallas=None`` resolves to.
 
-    Consults the loaded planner profile (``core.planner_profile``, the
-    telemetry written by ``tools/calibrate_planner.py``) for a measured
-    ``pallas_default`` verdict on this device; when no profile entry
-    covers the platform it warns once and falls back to the static oracle
-    (Pallas on TPU, XLA scatter-add elsewhere — interpret-mode Pallas is a
-    correctness oracle, not a fast path).  ``dense_coreness`` and
-    ``core.session`` both resolve through here — one place to change if
-    the policy ever widens."""
-    from .planner_profile import pallas_default
-    v = pallas_default(jax.default_backend())
-    if v is not None:
-        return v
-    return jax.default_backend() == "tpu"
+    The planner profile's measured verdict for this device
+    (``planner_profile.use_pallas_default``, keyed by device kind, then
+    platform), else Pallas on TPU.  ``dense_coreness`` and
+    ``core.session`` both resolve through here."""
+    from .planner_profile import use_pallas_default
+    return use_pallas_default(jax.default_backend(),
+                              jax.devices()[0].device_kind)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -465,12 +460,12 @@ def run_peel_engine(inc_rid, deg0, schedule: PeelSchedule, *,
 # Single-device dense backend: jitted entry + Pallas scatter plan
 # ---------------------------------------------------------------------------
 
-# Plan-memory ceiling for the round megakernel: the per-edge member matrix
-# is E * C int32 (each CSR edge carries its s-clique's full member row so
-# the in-kernel dead test needs no second indirection).  Past this the
-# scatter-only Pallas path (plan = 2 * E int32) takes over — fallback rule
-# #2 of DESIGN.md §9.
-MEGAKERNEL_PLAN_BUDGET_BYTES = 1 << 29
+def round_up_pow2_chunks(max_chunks: int, n_chunks: int) -> int:
+    """The chunk-span bound rounded up to a power of two (floor 8, capped
+    at the plan's chunk count) so it stops fragmenting a bucket's jit key."""
+    mc = max(max_chunks, 8)
+    mc = 1 << (mc - 1).bit_length()
+    return max(min(mc, n_chunks), 1)
 
 
 @partial(jax.jit, static_argnames=("schedule", "max_rounds", "spec",
@@ -510,20 +505,22 @@ def _dense_engine(inc_rid, deg0, plan_a, plan_b, peeled0, *,
         plan_rids, plan_sids = plan_a, plan_b
 
         def scatter(dead_now):
-            data = dead_now[plan_sids].astype(INT)[:, None]
+            # lane-major (1, E): an (E, 1) operand is padded 128-fold on TPU
+            data = dead_now[plan_sids].astype(INT)[None, :]
             out = segment_sum_sorted(data, plan_rids, spec.n_seg_pad,
                                      block_n=spec.block_n,
                                      chunk_e=spec.chunk_e,
                                      max_chunks=spec.max_chunks,
                                      interpret=spec.interpret)
-            return out[:n_r, 0]
+            return out[0, :n_r]
     return run_peel_engine(inc_rid, deg0, schedule, max_rounds=max_rounds,
                            scatter=scatter, fused_round=fused_round,
                            hierarchy=hierarchy, peeled0=peeled0)
 
 
 def _scatter_plan(problem: NucleusProblem, block_n: int, chunk_e: int,
-                  interpret: bool):
+                  interpret: bool, *, e_pad: Optional[int] = None,
+                  n_r_pad: Optional[int] = None, pow2_chunks: bool = False):
     """CSR edge arrays (rid-sorted) padded for the Pallas segment sum.
 
     Edge k of the flat CSR is (rid=plan_rids[k], sid=plan_sids[k]) with
@@ -531,8 +528,11 @@ def _scatter_plan(problem: NucleusProblem, block_n: int, chunk_e: int,
     per-round data vector is just ``dead_now[plan_sids]``.  Built once per
     (problem, kernel tiling) and memoized on the problem: the O(E) host
     expansion + device upload must not recur on every coreness call.
+    The optional pad overrides let ``core.session`` shape the plan to its
+    pow2 buckets so same-bucket problems share one executable;
+    ``pow2_chunks`` rounds the chunk-span bound (``round_up_pow2_chunks``).
     """
-    key = (block_n, chunk_e, interpret)
+    key = (block_n, chunk_e, interpret, e_pad, n_r_pad, pow2_chunks)
     cache = getattr(problem, "_scatter_plans", None)
     if cache is None:
         cache = {}
@@ -542,7 +542,11 @@ def _scatter_plan(problem: NucleusProblem, block_n: int, chunk_e: int,
     counts = np.diff(np.asarray(problem.mem_offsets))
     rids = np.repeat(np.arange(problem.n_r, dtype=np.int32), counts)
     rids_pad, n_seg_pad, max_chunks = sorted_ids_plan(
-        rids, problem.n_r, block_n=block_n, chunk_e=chunk_e)
+        rids, problem.n_r, block_n=block_n, chunk_e=chunk_e, e_pad=e_pad,
+        n_seg_pad=n_r_pad)
+    if pow2_chunks:
+        max_chunks = round_up_pow2_chunks(max_chunks,
+                                          rids_pad.shape[0] // chunk_e)
     sids_pad = np.zeros(rids_pad.shape[0], np.int32)
     sids_pad[:rids.shape[0]] = np.asarray(problem.mem_sids, np.int32)
     spec = ScatterSpec(block_n=block_n, chunk_e=chunk_e,
@@ -562,23 +566,14 @@ def _plan_arrays(problem: NucleusProblem):
 
 
 def _round_plan(problem: NucleusProblem, block_n: int, chunk_e: int,
-                interpret: bool, *, e_pad: Optional[int] = None,
-                n_r_pad: Optional[int] = None,
-                max_chunks: Optional[int] = None,
-                pow2_chunks: bool = False):
+                interpret: bool):
     """Megakernel plan: (ids, members, spec), memoized on the problem.
 
     Edge k of the flat CSR is rid ``ids[k]`` inside the s-clique whose full
     member row is ``members[k]`` — everything the fused dead test needs,
-    gathered once at plan-build time.  The optional pad overrides let
-    ``core.session`` shape the plan to its pow2 buckets so same-bucket
-    problems share one executable; ``pow2_chunks`` additionally rounds the
-    (data-dependent) per-block chunk-span bound up to a power of two
-    (floor 8, capped at the total chunk count) so it stops fragmenting the
-    bucket's jit key.
+    gathered once at plan-build time.
     """
-    key = ("round", block_n, chunk_e, interpret, e_pad, n_r_pad, max_chunks,
-           pow2_chunks)
+    key = ("round", block_n, chunk_e, interpret)
     cache = getattr(problem, "_scatter_plans", None)
     if cache is None:
         cache = {}
@@ -587,13 +582,7 @@ def _round_plan(problem: NucleusProblem, block_n: int, chunk_e: int,
         return cache[key]
     rids, members = _plan_arrays(problem)
     ids_pad, members_pad, n_r_pad, max_chunks = peel_round_plan(
-        rids, members, problem.n_r, block_n=block_n, chunk_e=chunk_e,
-        e_pad=e_pad, n_r_pad=n_r_pad, max_chunks=max_chunks)
-    if pow2_chunks:
-        mc = max(max_chunks, 8)
-        mc = 1 << (mc - 1).bit_length()
-        max_chunks = min(mc, ids_pad.shape[0] // chunk_e)
-        max_chunks = max(max_chunks, 1)
+        rids, members, problem.n_r, block_n=block_n, chunk_e=chunk_e)
     spec = ScatterSpec(block_n=block_n, chunk_e=chunk_e,
                        max_chunks=max_chunks, n_seg_pad=n_r_pad,
                        interpret=interpret)
@@ -619,14 +608,14 @@ def dense_coreness(problem: NucleusProblem, schedule: PeelSchedule, *,
     Raw bucket values are returned — approx clipping is the caller's job
     so the trace keeps the values that drove LINK equality.
 
-    With Pallas on, the round MEGAKERNEL (``kernels.peel_round``: select +
-    dead-s-clique gather + segment decrement in one launch) is the default
-    round body; the scatter-only Pallas path remains as the fallback when
-    the per-edge member plan would exceed MEGAKERNEL_PLAN_BUDGET_BYTES
-    (fused_kernel=True/False forces the choice; DESIGN.md §9 has the full
-    fallback ladder).  ``plan=(ids, members, spec)`` injects a prebuilt
-    megakernel plan — ``core.session`` passes its pow2-bucketed plan so
-    warm calls share one executable.
+    With Pallas on, the round body is the scatter-only Pallas plan: the
+    decrement runs as a sorted-segment sum over the rid-sorted CSR edge
+    array.  ``fused_kernel=True`` selects the round MEGAKERNEL
+    (``kernels.peel_round``) instead; it runs in interpret mode only and
+    raises ``ConfigError`` when it would be compiled.
+    ``plan=(rids, sids, spec)`` injects a prebuilt scatter plan —
+    ``core.session`` passes its pow2-bucketed plan so warm calls share one
+    executable.
 
     hierarchy=True fuses the ANH-EL link fixpoint into the same compiled
     call and appends the join forest (parent, L) to the return tuple.
@@ -645,20 +634,26 @@ def dense_coreness(problem: NucleusProblem, schedule: PeelSchedule, *,
     if use_pallas and problem.n_s > 0:
         if interpret is None:
             interpret = jax.default_backend() == "cpu"
+        if fused_kernel and not interpret:
+            # Mosaic (the TPU Pallas compiler) lowers only 2-D in-kernel
+            # gathers; the megakernel gathers from a full 1-D state vector
+            raise ConfigError(
+                "fused_kernel=True: the round megakernel gathers r-clique "
+                "state (peeled[members], deg[members]) from a full 1-D "
+                "vector inside the kernel, and Mosaic lowers only 2-D "
+                "gathers (NotImplementedError: Only 2D gather is "
+                "supported), so it cannot compile for a TPU; it runs in "
+                "interpret mode only.  Leave fused_kernel unset for the "
+                "scatter-only Pallas plan.")
         if plan is not None:
             plan_a, plan_b, spec = plan
+        elif fused_kernel:
+            plan_a, plan_b, spec = _round_plan(problem, block_n, chunk_e,
+                                               interpret)
             fused = True
         else:
-            plan_bytes = 4 * problem.n_s * problem.n_sub ** 2
-            if fused_kernel is None:
-                fused_kernel = plan_bytes <= MEGAKERNEL_PLAN_BUDGET_BYTES
-            if fused_kernel:
-                plan_a, plan_b, spec = _round_plan(problem, block_n,
-                                                   chunk_e, interpret)
-                fused = True
-            else:
-                plan_a, plan_b, spec = _scatter_plan(problem, block_n,
-                                                     chunk_e, interpret)
+            plan_a, plan_b, spec = _scatter_plan(problem, block_n, chunk_e,
+                                                 interpret)
     if peeled0 is None:
         peeled0 = jnp.zeros((problem.deg0.shape[0],), bool)
     return _dense_engine(problem.inc_rid, problem.deg0, plan_a, plan_b,

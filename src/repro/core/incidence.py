@@ -289,17 +289,13 @@ def _assemble(g: Graph, r: int, s: int, r_rows: np.ndarray,
 def _oriented_counts(dense: jnp.ndarray) -> jnp.ndarray:
     """(D @ Dᵀ) ⊙ D for the (2,3) count pass: the Pallas boolean-tile kernel
     on accelerators, the pure-jnp oracle on CPU (interpret-mode Pallas walks
-    the tile grid in Python — one XLA matmul is the honest CPU fallback) or
-    if the kernel launch fails."""
+    the tile grid in Python — one XLA matmul is the honest CPU path).  A
+    kernel failure on an accelerator propagates."""
     import jax
-    from ..kernels import ref
+    from ..kernels import ops, ref
     if jax.default_backend() == "cpu":
         return ref.tricount_oriented_ref(dense)
-    try:
-        from ..kernels import ops
-        return ops.tricount_oriented(dense)
-    except Exception:
-        return ref.tricount_oriented_ref(dense)
+    return ops.tricount_oriented(dense)
 
 
 def _fastpath_ok(r: int, s: int, dg: Digraph, budget: int) -> bool:

@@ -33,8 +33,8 @@ schedule, same trace semantics, same while_loop) and is declared as the
 ``"kcore"`` fast lane on the dense backend's capabilities so the planner
 records the routing in ``Plan.reasons``.  ``peel._run`` routes
 (r, s) = (1, 2) dense peels here unless the caller pins the Pallas
-megakernel path (``use_pallas=True`` keeps the generic engine so the
-megakernel stays exercised on r1s2 fixtures too).
+path (``use_pallas=True`` keeps the generic engine so the Pallas round
+body stays exercised on r1s2 fixtures too).
 """
 from __future__ import annotations
 

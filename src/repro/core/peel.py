@@ -118,7 +118,7 @@ def _run(problem: NucleusProblem, schedule: PeelSchedule,
     if backend == "dense":
         # the r1s2 degenerate case routes to the k-core fast lane (vertex
         # peel + one-shot edge-list fixpoint, ``core.kcore``) unless the
-        # caller pins the Pallas megakernel — the lane the dense backend
+        # caller pins the Pallas round body — the lane the dense backend
         # declares as "kcore" and the planner records in Plan.reasons.
         # fast_lane=True/False forces the routing (tests compare lanes).
         if fast_lane is None:

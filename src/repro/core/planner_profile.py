@@ -135,3 +135,14 @@ def pallas_default(platform: Optional[str] = None,
         f"platform oracle (Pallas on TPU).  Run tools/calibrate_planner.py "
         f"(or `make calibrate`) to measure this device.")
     return None
+
+
+def use_pallas_default(platform: Optional[str],
+                       device_kind: Optional[str] = None,
+                       path: Optional[str] = None) -> bool:
+    """What ``use_pallas=None`` resolves to on this device: the profile's
+    measured verdict when one covers it, else the static platform oracle
+    (Pallas on TPU, XLA scatter-add elsewhere — interpret-mode Pallas is
+    a correctness oracle, not a fast path)."""
+    v = pallas_default(platform, device_kind, path)
+    return v if v is not None else platform == "tpu"

@@ -26,19 +26,21 @@ of similar graphs pays the dominant cost — compilation — over and over.
     recompiling; ``Session.stats`` records the bucket hit pattern, and the
     ``session`` bench lane + EXPERIMENTS.md record the cold/warm speedup.
 
-Pallas configs ride the warm path too: the round-megakernel plan is
-padded to the same pow2 buckets (edge axis bucketed at floor ``chunk_e``,
-chunk-span bound pow2-rounded), so ``use_pallas=True`` — or a profile
-that defaults it on — reuses one executable per shape class instead of
+Pallas configs ride the warm path too: the scatter plan (the rid-sorted
+CSR edge arrays of ``engine._scatter_plan``) is padded to the same pow2
+buckets (edge axis bucketed at floor ``chunk_e``, chunk-span bound
+pow2-rounded), so ``use_pallas=True`` — or a profile that defaults it on,
+as on a TPU — reuses one executable per shape class instead of
 recompiling per problem.  Sharded configs ride it as well: the s-clique
 axis is padded to SHARD-MULTIPLE shape classes (``shard_bucket_size`` —
 pow2 alone slices raggedly when the mesh size is not a power of two;
 DESIGN.md §13) with ghost -1 rows, ghost r-cliques enter pre-peeled, and
 same-bucket problems reuse one ``shard_map`` executable through
 ``distributed._jitted_decomposition``.  Configs that resolve to any other
-non-dense backend, or whose megakernel plan would exceed its memory
-budget, fall back to the planned cold path (same ``Plan`` provenance,
-counted in ``stats["fallback"]``): correct, just not bucket-warmed.
+non-dense backend, or whose padded plan would exceed
+``PLAN_BUDGET_BYTES``, fall back to the planned cold path (same ``Plan``
+provenance, counted in ``stats["fallback"]``): correct, just not
+bucket-warmed.
 ``launch.serve --arch nucleus --warm-pool`` drives this end-to-end.
 """
 from __future__ import annotations
@@ -56,12 +58,15 @@ from ..graph import INT
 from ..kernels.segment_sum import DEFAULT_BLOCK_N, DEFAULT_CHUNK_E
 from .api import (Decomposition, NucleusConfig, execute_plan, plan_config,
                   resolve_problem)
-from .engine import (MEGAKERNEL_PLAN_BUDGET_BYTES, ScatterSpec, _round_plan,
-                     dense_coreness, pallas_by_default)
+from .engine import (ScatterSpec, _scatter_plan, dense_coreness,
+                     pallas_by_default, round_up_pow2_chunks)
 from .incidence import NucleusProblem
 from .schedule import PeelSchedule
 
 DEFAULT_BUCKET_FLOOR = 64
+# ceiling on ``padded_plan_bytes`` for the bucketed path and for serving
+# admission; a problem over it takes the cold path
+PLAN_BUDGET_BYTES = 1 << 29
 # default LRU bound on stats["buckets"]: generous for real serving mixes
 # (hundreds of shape classes) while keeping a long-lived process O(1)
 DEFAULT_BUCKET_CAP = 256
@@ -80,6 +85,18 @@ def bucket_size(n: int, floor: int = DEFAULT_BUCKET_FLOOR) -> int:
     executable."""
     n = max(int(n), int(floor), 1)
     return 1 << (n - 1).bit_length()
+
+
+def padded_plan_bytes(problem: NucleusProblem) -> int:
+    """The size estimate the bucketed path and serving admission are
+    gated on: 4 * e_pad * C bytes, an (e_pad, C) int32 member matrix with
+    the edge axis pow2-bucketed.  The scatter plan itself is two (e_pad,)
+    int32 arrays; the estimate stays at the size the warm path was sized
+    and tested at, since the engine's own device memory is many times
+    the plan's (PERF.md) and a higher admission ceiling needs that
+    measured at the larger buckets first."""
+    e_pad = bucket_size(problem.n_s * problem.n_sub, DEFAULT_CHUNK_E)
+    return 4 * e_pad * problem.n_sub
 
 
 def shard_bucket_size(n: int, n_shards: int,
@@ -136,7 +153,7 @@ class _Bucket:
     n_r_pad: int
     n_s_pad: int
     schedule: PeelSchedule
-    # the Pallas megakernel tiling this bucket compiles with (None = pure
+    # the Pallas scatter tiling this bucket compiles with (None = pure
     # XLA round body); the plan arrays are padded to the same pow2 buckets
     # (edge axis included) so warm members reuse the executable
     pallas: Optional[ScatterSpec] = None
@@ -219,21 +236,18 @@ class Session:
         config, plan = plan_config(problem, config)
         self._count("decompositions")
         # the padded path covers the compiled dense engine, XLA round body
-        # AND Pallas megakernel: the megakernel plan is padded to the same
-        # pow2 buckets (edge axis included), so use_pallas rides the warm
-        # path too.  Only a plan that would blow the megakernel's memory
-        # budget still takes the cold path (scatter-only fallback there).
+        # AND Pallas scatter plan: the plan is padded to the same pow2
+        # buckets (edge axis included), so use_pallas rides the warm path
+        # too.  Only a plan over PLAN_BUDGET_BYTES takes the cold path.
         wants_pallas = bool(config.use_pallas or (
             config.use_pallas is None and pallas_by_default()))
-        # gate on what the padded plan actually allocates — the member
-        # matrix is (e_pad, C) int32 with the edge axis pow2-bucketed, so
-        # a problem just under budget unpadded can land over it padded
-        e_pad = bucket_size(problem.n_s * problem.n_sub, DEFAULT_CHUNK_E)
-        plan_bytes = 4 * e_pad * problem.n_sub
+        # gate on what the padded plan actually allocates — a problem just
+        # under budget unpadded can land over it padded
+        plan_bytes = padded_plan_bytes(problem)
         if config.backend == "sharded" and problem.n_r > 0:
             return self._decompose_padded_sharded(problem, config, plan)
         if config.backend != "dense" or problem.n_r == 0 or (
-                wants_pallas and plan_bytes > MEGAKERNEL_PLAN_BUDGET_BYTES):
+                wants_pallas and plan_bytes > PLAN_BUDGET_BYTES):
             self._count("fallback")
             return execute_plan(problem, config, plan)
         return self._decompose_padded(problem, config, plan,
@@ -285,7 +299,7 @@ class Session:
             pallas=pallas_spec)
 
     def _pallas_plan(self, problem: NucleusProblem, n_r_pad: int):
-        """The bucketed megakernel plan: CSR edge arrays padded to pow2
+        """The bucketed scatter plan: CSR edge arrays padded to pow2
         shape classes (edge count included, floor ``chunk_e``) with a
         pow2-rounded chunk-span bound, so the ScatterSpec — part of the
         executable's jit key — repeats across same-bucket problems."""
@@ -293,24 +307,24 @@ class Session:
         e_real = int(problem.mem_sids.shape[0])
         e_pad = bucket_size(e_real, chunk_e)
         n_seg_pad = max(n_r_pad, block_n)
-        return _round_plan(problem, block_n, chunk_e,
-                           jax.default_backend() == "cpu",
-                           e_pad=e_pad, n_r_pad=n_seg_pad,
-                           pow2_chunks=True)
+        return _scatter_plan(problem, block_n, chunk_e,
+                             jax.default_backend() == "cpu",
+                             e_pad=e_pad, n_r_pad=n_seg_pad,
+                             pow2_chunks=True)
 
     def _pallas_spec(self, problem: NucleusProblem,
                      n_r_pad: int) -> ScatterSpec:
         """``_pallas_plan``'s ScatterSpec without the plan arrays.
 
         Keys must be cheap: a bucket probe that materializes the full
-        padded (e_pad, C) member matrix on device just to hash a tiling
+        padded edge arrays on device just to hash a tiling
         is most of a cold plan's cost.  Every spec field is derived here
         from the mem-CSR offsets alone.  The one data-dependent field,
         the chunk-span bound, only reads the padded rid stream at chunk
         boundaries: rid(k) for k < E is the CSR row containing slot k
         (``searchsorted(offsets[1:], k, 'right')``), and every padded
         slot holds the ``n_seg_pad`` sentinel.  The c0/c1 span count and
-        pow2 rounding mirror ``peel_round_plan`` / ``_round_plan`` —
+        pow2 rounding mirror ``sorted_ids_plan`` / ``_scatter_plan`` —
         ``_decompose_padded`` asserts the twin agrees with the real plan
         whenever one is built."""
         block_n, chunk_e = DEFAULT_BLOCK_N, DEFAULT_CHUNK_E
@@ -331,9 +345,7 @@ class Session:
         c0 = np.searchsorted(chunk_last, bounds_lo, side="left")
         c1 = np.searchsorted(chunk_first, bounds_lo + block_n, side="left")
         need = max(int(np.max(np.maximum(c1 - c0, 0), initial=0)), 1)
-        mc = max(need, 8)
-        mc = 1 << (mc - 1).bit_length()
-        max_chunks = max(min(mc, n_chunks), 1)
+        max_chunks = round_up_pow2_chunks(need, n_chunks)
         return ScatterSpec(block_n=block_n, chunk_e=chunk_e,
                            max_chunks=max_chunks, n_seg_pad=n_seg_pad,
                            interpret=jax.default_backend() == "cpu")
@@ -422,7 +434,7 @@ class Session:
                 # ghost plan arrays: every slot the padding sentinel —
                 # the VALUES never enter the jit key, only the shapes do
                 plan = (jnp.full((e_pad,), spec.n_seg_pad, INT),
-                        jnp.full((e_pad, C), -1, INT), spec)
+                        jnp.zeros((e_pad,), INT), spec)
             out = dense_coreness(ghost, sched, use_pallas=spec is not None,
                                  max_rounds=n_r_pad + 2,
                                  hierarchy=bool(e["fused"]),

@@ -20,6 +20,23 @@ import jax.numpy as jnp
 from .container import INT
 
 
+def _varying(x, *like):
+    """``x`` cast to the union of the varying manual axes of ``like``.
+
+    Under ``shard_map`` a ``while_loop`` carry must keep its type across
+    the body; a flag or label array born replicated but updated from
+    device-varying operands would change type, so the initial carry is
+    cast up front.  Outside ``shard_map`` there are no such axes and
+    ``x`` passes through."""
+    axes = set()
+    for y in like:
+        axes |= set(jax.typeof(y).vma)
+    axes -= set(jax.typeof(x).vma)
+    if not axes:
+        return x
+    return jax.lax.pcast(x, tuple(sorted(axes)), to="varying")
+
+
 def pointer_jump(labels: jnp.ndarray, iters: int | None = None) -> jnp.ndarray:
     """Resolve label forest to roots: labels <- labels[labels] to fixpoint.
 
@@ -44,7 +61,8 @@ def pointer_jump(labels: jnp.ndarray, iters: int | None = None) -> jnp.ndarray:
         return nxt, jnp.any(nxt != l), i + 1
 
     out, _, _ = jax.lax.while_loop(
-        cond, body, (labels, jnp.asarray(True), jnp.zeros((), INT)))
+        cond, body, (labels, _varying(jnp.asarray(True), labels),
+                     jnp.zeros((), INT)))
     return out
 
 
@@ -80,5 +98,7 @@ def connected_components(n: int, u: jnp.ndarray, v: jnp.ndarray,
         new = hook(l)
         return new, jnp.any(new != l)
 
-    labels, _ = jax.lax.while_loop(cond, body, (labels, jnp.asarray(True)))
+    labels = _varying(labels, u, v)
+    labels, _ = jax.lax.while_loop(
+        cond, body, (labels, _varying(jnp.asarray(True), labels)))
     return labels

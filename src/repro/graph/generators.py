@@ -38,6 +38,34 @@ def barabasi_albert(n: int, k: int, seed: int = 0) -> Graph:
     return make_graph(n, np.asarray(edges, dtype=np.int64))
 
 
+def kronecker(scale: int, edge_factor: int = 16, seed: int = 0) -> Graph:
+    """Graph500 Kronecker graph: 2**scale vertices, edge_factor * 2**scale
+    sampled edges before self-loops and duplicates are dropped.
+
+    Each edge picks one quadrant of the adjacency matrix per bit level
+    with the Graph500 initiator probabilities A, B, C, D = 0.57, 0.19,
+    0.19, 0.05; vertex ids are then permuted at random, so ids carry no
+    degree information.  Vectorized over edges (O(m * scale) numpy work).
+    """
+    a, b, c = 0.57, 0.19, 0.19
+    n = 1 << scale
+    m = edge_factor * n
+    rng = np.random.default_rng(seed)
+    c_norm = c / (1.0 - (a + b))
+    a_norm = a / (a + b)
+    u = np.zeros(m, np.int64)
+    v = np.zeros(m, np.int64)
+    for bit in range(scale):
+        u_bit = rng.random(m) > (a + b)
+        v_bit = rng.random(m) > np.where(u_bit, c_norm, a_norm)
+        u |= u_bit.astype(np.int64) << bit
+        v |= v_bit.astype(np.int64) << bit
+    perm = rng.permutation(n)
+    u, v = perm[u], perm[v]
+    keep = u != v
+    return make_graph(n, np.stack([u[keep], v[keep]], axis=1))
+
+
 def planted_cliques(n: int, clique_sizes, p_background: float = 0.01,
                     seed: int = 0) -> Graph:
     """Background ER graph + planted cliques => a known nested-density structure."""

@@ -72,12 +72,12 @@ def segment_sum(data: jnp.ndarray, ids: jnp.ndarray, n_segments: int,
                 block_n: int = 128, chunk_e: int = 512,
                 max_chunks: int | None = None,
                 interpret: bool | None = None) -> jnp.ndarray:
-    """Sorted-segment sum; pads rows (id = n_segments) and segments."""
-    E, d = data.shape
-    dp, _ = _pad_to(data, 0, chunk_e)
+    """Sorted-segment sum of (E, d) rows -> (n_segments, d); pads rows
+    (id = n_segments) and segments, and feeds the kernel lane-major."""
+    dp, _ = _pad_to(data.T, 1, chunk_e)
     idp, _ = _pad_to(ids, 0, chunk_e, value=n_segments)
     n_pad = -(-n_segments // block_n) * block_n + block_n  # room for pad ids
     out = segment_sum_sorted(dp, idp.astype(jnp.int32), n_pad,
                              block_n=block_n, chunk_e=chunk_e,
                              max_chunks=max_chunks, interpret=interpret)
-    return out[:n_segments]
+    return out[:, :n_segments].T

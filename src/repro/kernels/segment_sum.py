@@ -4,7 +4,7 @@
 SORTED segment ids (what a CSR edge array gives for free) the reduction is a
 band-structured one-hot matmul:
 
-    out[n0:n1] = Σ_chunks onehot(ids_chunk, [n0, n1)) @ data_chunk
+    out[:, n0:n1] = Σ_chunks data_chunk @ onehot(ids_chunk, [n0, n1))ᵀ
 
 Grid = (out_blocks, chunks_per_block).  A scalar-prefetch array `chunk0[i]`
 (first input chunk touching output block i, via searchsorted on the host/XLA
@@ -32,19 +32,26 @@ DEFAULT_CHUNK_E = 512
 
 def sorted_ids_plan(ids: np.ndarray, n_segments: int,
                     block_n: int = DEFAULT_BLOCK_N,
-                    chunk_e: int = DEFAULT_CHUNK_E):
+                    chunk_e: int = DEFAULT_CHUNK_E,
+                    e_pad: int | None = None,
+                    n_seg_pad: int | None = None):
     """Pad a concrete sorted id array so `segment_sum_sorted` jits.
 
     Returns ``(ids_padded, n_seg_pad, max_chunks)``: ids padded to a chunk_e
     multiple (pad id = n_seg_pad, outside every output block), the segment
     count padded to a block_n multiple, and the static per-block chunk-span
-    bound the kernel needs under jit.  Everything here is eager numpy — call
+    bound the kernel needs under jit.  ``e_pad``/``n_seg_pad`` override the
+    minimal pads (the Session passes its pow2 bucket shapes so same-bucket
+    problems share one executable).  Everything here is eager numpy — call
     it once at plan-build time, then feed the jitted hot loop.
     """
     ids = np.asarray(ids, np.int32)
-    n_seg_pad = -(-max(n_segments, 1) // block_n) * block_n
+    if n_seg_pad is None:
+        n_seg_pad = -(-max(n_segments, 1) // block_n) * block_n
+    assert n_seg_pad % block_n == 0 and n_seg_pad >= n_segments
     E = ids.shape[0]
-    E_pad = -(-max(E, 1) // chunk_e) * chunk_e
+    E_pad = e_pad if e_pad is not None else -(-max(E, 1) // chunk_e) * chunk_e
+    assert E_pad % chunk_e == 0 and E_pad >= E
     ids_padded = np.full(E_pad, n_seg_pad, np.int32)
     ids_padded[:E] = ids
     # same intersection logic as segment_sum_sorted, concretely
@@ -69,17 +76,19 @@ def _segsum_kernel(chunk0_ref, nchunks_ref, ids_ref, data_ref, out_ref,
     @pl.when(j < nchunks_ref[i])
     def _body():
         ids = ids_ref[...]                       # (1, chunk_e) int32
-        data = data_ref[...]                     # (chunk_e, d)
+        data = data_ref[...]                     # (d, chunk_e)
         n0 = i * block_n
         rows = n0 + jax.lax.broadcasted_iota(jnp.int32,
                                              (block_n, chunk_e), 0)
         onehot = (ids == rows).astype(jnp.float32)   # (block_n, chunk_e)
-        acc_ref[...] += jax.lax.dot(onehot, data.astype(jnp.float32),
-                                    preferred_element_type=jnp.float32)
+        # (d, chunk_e) x (block_n, chunk_e)^T -> (d, block_n)
+        acc_ref[...] += jax.lax.dot_general(
+            data.astype(jnp.float32), onehot, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     @pl.when(j == max_chunks - 1)
     def _finish():
-        out_ref[0] = acc_ref[...].astype(out_ref.dtype)
+        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
 
 def segment_sum_sorted(data: jnp.ndarray, ids: jnp.ndarray, n_segments: int,
@@ -87,14 +96,17 @@ def segment_sum_sorted(data: jnp.ndarray, ids: jnp.ndarray, n_segments: int,
                        chunk_e: int = DEFAULT_CHUNK_E,
                        max_chunks: int | None = None,
                        interpret: bool | None = None) -> jnp.ndarray:
-    """data: (E, d), ids: (E,) int32 SORTED ascending -> (n_segments, d).
+    """data: (d, E), ids: (E,) int32 SORTED ascending -> (d, n_segments).
 
+    Both the per-edge operand and the result are lane-major (the edge and
+    segment axes are minor): the TPU tiles the two minor dims of an HBM
+    array by (8, 128), so an (E, 1) operand would be padded 128-fold.
     E % chunk_e == 0 and n_segments % block_n == 0 (pad at the wrapper; use
     id = n_segments for padding rows — they fall outside every block).
     """
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    E, d = data.shape
+    d, E = data.shape
     assert E % chunk_e == 0 and n_segments % block_n == 0
     n_blocks = n_segments // block_n
     n_chunks_total = E // chunk_e
@@ -120,18 +132,13 @@ def segment_sum_sorted(data: jnp.ndarray, ids: jnp.ndarray, n_segments: int,
     grid = (n_blocks, max_chunks)
     ids2d = ids.reshape(1, E)
 
-    def ids_map(i, j, chunk0_ref, nchunks_ref):
+    def edge_map(i, j, chunk0_ref, nchunks_ref):
         k = chunk0_ref[i] + jnp.minimum(j, nchunks_ref[i] - 1)
         k = jnp.clip(k, 0, n_chunks_total - 1)
         return (0, k)
 
-    def data_map(i, j, chunk0_ref, nchunks_ref):
-        k = chunk0_ref[i] + jnp.minimum(j, nchunks_ref[i] - 1)
-        k = jnp.clip(k, 0, n_chunks_total - 1)
-        return (k, 0)
-
     def out_map(i, j, chunk0_ref, nchunks_ref):
-        return (i, 0, 0)
+        return (0, i)
 
     return pl.pallas_call(
         partial(_segsum_kernel, block_n=block_n, chunk_e=chunk_e,
@@ -140,12 +147,12 @@ def segment_sum_sorted(data: jnp.ndarray, ids: jnp.ndarray, n_segments: int,
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, chunk_e), ids_map),
-                pl.BlockSpec((chunk_e, d), data_map),
+                pl.BlockSpec((1, chunk_e), edge_map),
+                pl.BlockSpec((d, chunk_e), edge_map),
             ],
-            out_specs=pl.BlockSpec((1, block_n, d), out_map),
-            scratch_shapes=[pltpu.VMEM((block_n, d), jnp.float32)],
+            out_specs=pl.BlockSpec((d, block_n), out_map),
+            scratch_shapes=[pltpu.VMEM((d, block_n), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((n_blocks, block_n, d), data.dtype),
+        out_shape=jax.ShapeDtypeStruct((d, n_segments), data.dtype),
         interpret=interpret,
-    )(c0, nchunks, ids2d, data).reshape(n_segments, d)
+    )(c0, nchunks, ids2d, data)

@@ -269,14 +269,6 @@ def _extrapolate_lm_cost(spec: ArchSpec, cell: ShapeCell, mesh: Mesh,
     return cost_x, coll_x
 
 
-def _mesh_context(mesh: Mesh):
-    """Mesh context manager across jax versions: jax.set_mesh exists from
-    0.6 on; in 0.4.x the Mesh object itself is the context manager."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
 def run_cell(arch_id: str, shape_name: str, multi_pod: bool,
              opt_overrides: Optional[Dict[str, Any]] = None,
              tag: str = "") -> Dict[str, Any]:
@@ -295,7 +287,7 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool,
     t0 = time.time()
     # mesh context: required for PartitionSpec-based sharding constraints
     # inside the models (jax.lax.with_sharding_constraint)
-    with _mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = lower_cell(spec, cell, mesh, opt_overrides)
     t1 = time.time()
     compiled = lowered.compile()
@@ -307,7 +299,7 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool,
     cost_x = coll_x = None
     if spec.family == "lm":
         try:
-            with _mesh_context(mesh):
+            with jax.set_mesh(mesh):
                 cost_x, coll_x = _extrapolate_lm_cost(spec, cell, mesh,
                                                       opt_overrides)
         except Exception as e:

@@ -16,14 +16,15 @@ plan-aware ``repro.serve.Router`` (``--r/--s/--method`` accept comma lists,
 so the pool exercises mixed tenant configs across per-config Sessions).
 ``--server`` starts the real multi-tenant front end (DESIGN.md §11): the
 bounded-queue ``Frontend`` + stdlib HTTP surface, with ``--cache-dir``
-wiring the persistent compilation cache + session manifest so a restarted
-server pre-warms its pools; ``--selftest`` drives a short mixed workload
+holding the session manifest so a restarted server pre-warms its pools
+from the persistent compilation cache; ``--selftest`` drives a short mixed workload
 over HTTP (decompose + query + update + status) and exits — the CI smoke.
 """
 from __future__ import annotations
 
 import argparse
 import json
+import os
 import time
 from functools import partial
 from typing import Dict, List, Optional, Tuple
@@ -382,22 +383,22 @@ def serve_nucleus_server(port: int = 0, cache_dir: str = "",
                          quiet: bool = False):
     """The real multi-tenant server (DESIGN.md §11).
 
-    Builds the Router -> Frontend -> HTTP stack; with ``--cache-dir`` it
-    first wires jax's persistent compilation cache and, if a session
-    manifest from a previous run exists there, pre-warms the pools so the
-    first same-bucket decompose after restart is a compile-cache hit.  On
-    shutdown the manifest is (re)saved.  ``--selftest`` drives the mixed
+    Builds the Router -> Frontend -> HTTP stack; with ``--cache-dir``, if
+    a session manifest from a previous run exists there, it pre-warms the
+    pools so the first same-bucket decompose after restart is a hit in
+    the persistent compilation cache (``main`` turns that cache on,
+    ``serve.cache.init_persistent_cache``).  On shutdown the manifest is
+    (re)saved.  ``--selftest`` drives the mixed
     CI-smoke workload over HTTP and exits; without it the server blocks
     until SIGINT.
     """
-    from ..serve import (Frontend, NucleusHTTPServer, Router,
-                         init_persistent_cache, load_manifest,
+    from ..serve import (Frontend, NucleusHTTPServer, Router, load_manifest,
                          prewarm_router, save_manifest)
 
     router = Router()
     prewarmed = 0
     if cache_dir:
-        init_persistent_cache(cache_dir)
+        os.makedirs(cache_dir, exist_ok=True)
         manifest = load_manifest(cache_dir)
         if manifest is not None:
             prewarmed = prewarm_router(router, manifest)
@@ -467,14 +468,18 @@ def main() -> None:
     ap.add_argument("--port", type=int, default=0,
                     help="--server port (0 = ephemeral)")
     ap.add_argument("--cache-dir", default="",
-                    help="--server: persistent compilation cache + "
-                         "session manifest directory (restart warm path)")
+                    help="--server: session manifest directory (restart "
+                         "warm path); the compilation cache is placed by "
+                         "JAX_COMPILATION_CACHE_DIR, else <checkout>/"
+                         ".jax_cache")
     ap.add_argument("--selftest", action="store_true",
                     help="--server: drive the mixed smoke workload over "
                          "HTTP, assert the status schema, and exit")
     args = ap.parse_args()
     setup_platform(platform=args.platform or None,
                    cpu_devices=args.cpu_devices or None)
+    from ..serve.cache import init_persistent_cache
+    init_persistent_cache()
     if args.arch == "nucleus":
         if args.server:
             serve_nucleus_server(port=args.port, cache_dir=args.cache_dir,

@@ -5,29 +5,27 @@ XLA compile for each shape bucket before the pool is warm again — the
 dominant serving cost, multiplied by every deploy.  Two pieces make the
 pool survive restarts:
 
-  * **Persistent compilation cache.**  ``init_persistent_cache(dir)``
-    points jax's on-disk executable cache at ``dir`` (min-entry-size and
+  * **Persistent compilation cache.**  ``init_persistent_cache()`` turns
+    on jax's on-disk executable cache (min-entry-size and
     min-compile-time gates opened so even small peel executables
-    persist).  Compiles keyed on the same HLO — same padded shapes, same
+    persist).  Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax's own
+    setting places it; otherwise it lives at the fixed in-checkout path
+    ``DEFAULT_CACHE_DIR``.  The cache key includes the path, so a path
+    built from a temporary name, a process id or the time would never
+    hit.  Compiles keyed on the same HLO — same padded shapes, same
     statics — are then disk loads in any later process.
   * **Session manifest.**  ``save_manifest``/``load_manifest`` persist
     ``Router.manifest()`` (one ``Session.manifest()`` per pool: the
-    shape-class records, nothing graph-specific) as JSON next to the
-    cache.  ``Router.prewarm(manifest)`` recreates each pool and runs
-    every bucket's all-ghost twin through the engine, turning the disk
-    cache into live jitted callables — the first post-restart
-    same-bucket decompose is a warm hit, not a multi-second compile
-    (the ``server`` bench lane records the >= 3x restart claim).
-
-``init_persistent_cache`` degrades gracefully: a jax build without the
-persistent-cache config options (or one that rejects them) logs and
-returns False — serving continues with in-process warmth only.
+    shape-class records, nothing graph-specific) as JSON.
+    ``Router.prewarm(manifest)`` recreates each pool and runs every
+    bucket's all-ghost twin through the engine, turning the disk cache
+    into live jitted callables — the first post-restart same-bucket
+    decompose is a warm hit, not a multi-second compile.
 """
 from __future__ import annotations
 
 import json
 import os
-import warnings
 from typing import Any, Dict, Optional
 
 from ..core.session import MANIFEST_FORMAT
@@ -37,35 +35,32 @@ ROUTER_MANIFEST_VERSION = 1
 MANIFEST_BASENAME = "session_manifest.json"
 
 
-def init_persistent_cache(cache_dir: str, *,
-                          min_entry_size_bytes: int = -1,
-                          min_compile_time_secs: float = 0.0) -> bool:
-    """Enable jax's on-disk compilation cache at ``cache_dir``.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
-    Must run before the executables of interest compile (ideally at
-    process start, right after ``launch.platform.setup_platform``).  The
-    default gates are opened fully (``-1`` / ``0.0``): peel executables
-    for small shape buckets compile fast enough that jax's stock
-    thresholds would skip exactly the entries a restarted server needs.
-    Returns True if the cache was wired, False (with a warning) when
-    this jax build lacks the config knobs.
+
+def init_persistent_cache() -> str:
+    """Turn on jax's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, stands as jax read it and no
+    other directory is set; otherwise the cache goes to
+    ``DEFAULT_CACHE_DIR`` (``<checkout>/.jax_cache``).  Must run before
+    the executables of interest compile (at process start, right after
+    ``launch.platform.setup_platform``).  The size and compile-time gates
+    are opened fully: peel executables for small shape buckets compile
+    fast enough that jax's stock thresholds would skip exactly the
+    entries a restarted server needs.
     """
     import jax
 
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir",
-                          os.fspath(cache_dir))
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                          int(min_entry_size_bytes))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          float(min_compile_time_secs))
-    except Exception as e:  # older jax: knob missing/renamed — degrade
-        warnings.warn(
-            f"persistent compilation cache unavailable ({e!r}); serving "
-            f"continues with in-process warmth only", RuntimeWarning)
-        return False
-    return True
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = DEFAULT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return cache_dir
 
 
 def router_manifest(router) -> Dict[str, Any]:

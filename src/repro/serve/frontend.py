@@ -10,9 +10,8 @@ engine-level locking at all.
 
   * **Admission control.**  ``submit()`` resolves the request's problem
     and computes the *padded* plan-budget estimate — the same
-    ``4 * bucket_size(n_s * C, CHUNK_E) * C`` bytes the Session's
-    megakernel gate uses, i.e. what the bucketed engine would actually
-    allocate — and rejects over-budget graphs up front with a typed
+    ``session.padded_plan_bytes`` the Session's plan gate uses — and
+    rejects over-budget graphs up front with a typed
     ``AdmissionError`` carrying the computed bytes.  A full queue is a
     typed ``QueueFullError`` (backpressure, not silent buffering).
   * **Coalescing.**  The worker drains whatever is queued, groups
@@ -34,10 +33,8 @@ import time
 from concurrent.futures import Future
 from typing import Any, Dict, List, Optional, Tuple
 
-from ..core.engine import MEGAKERNEL_PLAN_BUDGET_BYTES
 from ..core.incidence import NucleusProblem
-from ..core.session import bucket_size
-from ..kernels.segment_sum import DEFAULT_CHUNK_E
+from ..core.session import PLAN_BUDGET_BYTES, padded_plan_bytes
 from .router import Request, Router, canonical_config, pool_key
 
 
@@ -60,15 +57,6 @@ class QueueFullError(RuntimeError):
     retry after the pool drains)."""
 
 
-def padded_plan_bytes(problem: NucleusProblem) -> int:
-    """What the bucketed engine would allocate for ``problem``: the
-    (e_pad, C) int32 member matrix with the edge axis pow2-bucketed —
-    the same estimate ``Session.decompose`` gates the megakernel on
-    (DESIGN.md §8/§9), reused here as the admission formula."""
-    e_pad = bucket_size(problem.n_s * problem.n_sub, DEFAULT_CHUNK_E)
-    return 4 * e_pad * problem.n_sub
-
-
 @dataclasses.dataclass
 class _Job:
     request: Request
@@ -83,14 +71,15 @@ class Frontend:
 
     ``max_queue`` bounds in-flight work (admission is per-graph, the
     queue bound is per-server); ``admission_budget_bytes`` defaults to
-    the engine's megakernel plan budget.  ``start()``/``stop()`` manage
+    the Session's plan budget (``session.PLAN_BUDGET_BYTES``).
+    ``start()``/``stop()`` manage
     the worker thread; ``stop()`` drains nothing — queued futures are
     cancelled so shutdown is prompt and explicit.
     """
 
     def __init__(self, router: Optional[Router] = None, *,
                  max_queue: int = 64,
-                 admission_budget_bytes: int = MEGAKERNEL_PLAN_BUDGET_BYTES,
+                 admission_budget_bytes: int = PLAN_BUDGET_BYTES,
                  batch_wait_s: float = 0.002):
         self.router = router if router is not None else Router()
         self.admission_budget_bytes = int(admission_budget_bytes)

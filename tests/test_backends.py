@@ -202,13 +202,16 @@ def test_conformance_every_legal_triple(problem, method, backend, hierarchy):
 # Planner decision rules (explicit facts -> deterministic choices)
 # ---------------------------------------------------------------------------
 
-def _plan(cfg, *, n_r=1000, n_s=1000, n_sub=3, device_kind="cpu",
-          n_devices=1, profile_path="/nonexistent/planner_profile.json"):
+def _plan(cfg, *, n_r=1000, n_s=1000, n_sub=3, platform="cpu",
+          device_kind=None, n_devices=1,
+          profile_path="/nonexistent/planner_profile.json"):
     # profile_path defaults to a missing file so the decision-rule tests
-    # exercise the static constants regardless of the committed profile
+    # exercise the static constants regardless of the committed profile;
+    # a cpu device reports device_kind "cpu"
     return B.resolve_plan(cfg, n_r=n_r, n_s=n_s, n_sub=n_sub,
-                          device_kind=device_kind, n_devices=n_devices,
-                          profile_path=profile_path)
+                          platform=platform,
+                          device_kind=device_kind or platform,
+                          n_devices=n_devices, profile_path=profile_path)
 
 
 def test_planner_explicit_backend_is_kept():
@@ -233,8 +236,11 @@ def test_planner_pallas_forces_dense():
 
 
 def test_planner_accelerator_prefers_dense():
-    p = _plan(NucleusConfig(backend="auto"), device_kind="tpu")
+    p = _plan(NucleusConfig(backend="auto"), platform="tpu",
+              device_kind="TPU v5 lite")
     assert p.backend == "dense"
+    # the TPU default kernel is the compiled Pallas scatter plan
+    assert any("Pallas" in r and "interpret=False" in r for r in p.reasons)
 
 
 def test_planner_cpu_tiny_prefers_gather_else_dense():
@@ -333,6 +339,17 @@ def test_profile_device_kind_beats_platform(tmp_path):
     assert entry["tiny_nr"] == 10 and "TPU v4" in source
 
 
+def test_planner_finds_the_device_kind_entry(tmp_path):
+    """The planner looks the profile up by the real device kind
+    ("TPU v5 lite"), not by the platform string."""
+    path = _write_profile(tmp_path, {
+        "TPU v5 lite": {"tiny_nr": 7, "pallas_default": False}})
+    p = _plan(NucleusConfig(backend="auto"), platform="tpu",
+              device_kind="TPU v5 lite", profile_path=path)
+    assert any("planner_profile['TPU v5 lite']" in r for r in p.reasons)
+    assert any("XLA scatter-add" in r for r in p.reasons)
+
+
 def test_profile_per_key_fallback(tmp_path):
     """An entry that measured only one crossover keeps the static value
     for the other (shard_min_incidence is unmeasured on 1 device)."""
@@ -398,12 +415,14 @@ def test_planner_records_kcore_fast_lane():
     p = _plan(NucleusConfig(backend="auto", hierarchy="auto"))
     assert not any("kcore" in r for r in p.reasons)   # r/s unknown
     p12 = B.resolve_plan(NucleusConfig(backend="auto", hierarchy="auto"),
-                         n_r=1000, n_s=1000, n_sub=2, device_kind="cpu",
+                         n_r=1000, n_s=1000, n_sub=2, platform="cpu",
+                         device_kind="cpu",
                          n_devices=1, r=1, s=2,
                          profile_path="/nonexistent/planner_profile.json")
     assert any("fast lane 'kcore'" in r for r in p12.reasons)
     p23 = B.resolve_plan(NucleusConfig(backend="auto", hierarchy="auto"),
-                         n_r=1000, n_s=1000, n_sub=3, device_kind="cpu",
+                         n_r=1000, n_s=1000, n_sub=3, platform="cpu",
+                         device_kind="cpu",
                          n_devices=1, r=2, s=3,
                          profile_path="/nonexistent/planner_profile.json")
     assert not any("kcore" in r for r in p23.reasons)

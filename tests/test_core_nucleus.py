@@ -182,6 +182,21 @@ def _edge_density_bruteforce(g_edges, vertices):
     return inside / (k * (k - 1) / 2)
 
 
+def test_kronecker_generator_is_seeded_and_simple():
+    """Graph500 Kronecker: 2**scale vertices, deterministic per seed, no
+    self-loops, canonical (u < v) unique edges."""
+    a = generators.kronecker(8, edge_factor=16, seed=3)
+    b = generators.kronecker(8, edge_factor=16, seed=3)
+    c = generators.kronecker(8, edge_factor=16, seed=4)
+    assert a.n == 256
+    ea, eb = np.asarray(a.edges), np.asarray(b.edges)
+    np.testing.assert_array_equal(ea, eb)
+    assert not np.array_equal(ea, np.asarray(c.edges))
+    assert np.all(ea[:, 0] < ea[:, 1])
+    assert len({tuple(e) for e in ea.tolist()}) == a.m
+    assert 0 < a.m <= 16 * 256
+
+
 def test_edge_density_matches_bruteforce():
     rng = np.random.default_rng(7)
     g = generators.erdos_renyi(30, 0.2, seed=9)

@@ -181,31 +181,43 @@ def test_engine_empty_problem():
 
 
 # ---------------------------------------------------------------------------
-# Round megakernel (use_pallas=True now runs the fused round, not just the
-# scatter): full-peel bit-identity incl. the fused hierarchy
+# Pallas round bodies (the scatter-only plan, the default, and the round
+# megakernel, interpret mode only): full-peel bit-identity incl. the fused
+# hierarchy
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("kernel", ["scatter", "megakernel"])
 @pytest.mark.parametrize("mode", ["exact", "approx"])
 @pytest.mark.parametrize("gname,r,s", problems())
-def test_megakernel_full_peel_bit_identical(gname, r, s, mode):
-    """The fused round megakernel must reproduce the multi-op XLA round
-    body bit-for-bit across the whole peel — cores, trace, rounds AND the
+def test_megakernel_full_peel_bit_identical(gname, r, s, mode, kernel):
+    """Each Pallas round body must reproduce the multi-op XLA round body
+    bit-for-bit across the whole peel — cores, trace, rounds AND the
     fused LINK forest (the forest consumes the per-round a_mask, so it
     would catch a divergence the final cores might mask)."""
+    from repro.core.engine import dense_coreness, make_schedule
     p = build_problem(GRAPHS[gname], r, s)
     if p.n_r == 0:
         pytest.skip("no r-cliques")
-    peel = (exact_coreness if mode == "exact"
-            else lambda q, **kw: approx_coreness(q, delta=0.1, **kw))
-    ref = peel(p, backend="dense", use_pallas=False, hierarchy=True,
-               fast_lane=False)
-    mk = peel(p, backend="dense", use_pallas=True, hierarchy=True,
-              fast_lane=False)
-    for f in ("core", "order_round", "uf_parent", "uf_L"):
-        np.testing.assert_array_equal(np.asarray(getattr(ref, f)),
-                                      np.asarray(getattr(mk, f)),
+    sched = make_schedule(p, mode, 0.1)
+    ref = dense_coreness(p, sched, use_pallas=False, hierarchy=True)
+    got = dense_coreness(p, sched, use_pallas=True, hierarchy=True,
+                         interpret=True,
+                         fused_kernel=kernel == "megakernel")
+    for f, a, b in zip(("core", "order_round", "rounds", "uf_parent",
+                        "uf_L"), ref, got):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                       err_msg=f)
-    assert ref.rounds == mk.rounds
+
+
+def test_compiled_megakernel_is_refused():
+    """The megakernel's in-kernel 1-D gather cannot lower for a TPU, so a
+    compiled (non-interpret) megakernel must raise before any compile."""
+    from repro.core import ConfigError
+    from repro.core.engine import dense_coreness, make_schedule
+    p = build_problem(GRAPHS["planted"], 2, 3)
+    with pytest.raises(ConfigError, match="2D gather"):
+        dense_coreness(p, make_schedule(p, "exact"), use_pallas=True,
+                       interpret=False, fused_kernel=True)
 
 
 # ---------------------------------------------------------------------------
@@ -237,7 +249,7 @@ def test_kcore_lane_bit_identical(gname, mode):
 
 def test_kcore_lane_is_the_r1s2_default():
     """peel._run routes (1,2) dense peels to the k-core lane unless the
-    caller pins the Pallas megakernel."""
+    caller pins the Pallas round body."""
     from repro.core import peel as peel_mod
     calls = []
     orig = peel_mod.kcore_coreness
@@ -252,7 +264,7 @@ def test_kcore_lane_is_the_r1s2_default():
         exact_coreness(p, backend="dense")
         assert len(calls) == 1          # default: lane taken
         exact_coreness(p, backend="dense", use_pallas=True)
-        assert len(calls) == 1          # pinned megakernel: lane skipped
+        assert len(calls) == 1          # pinned Pallas: lane skipped
     finally:
         peel_mod.kcore_coreness = orig
 

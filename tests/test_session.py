@@ -172,7 +172,7 @@ def test_fallback_backends_still_work():
 
 
 def test_use_pallas_rides_the_warm_path():
-    """The round megakernel is bucketed like everything else: a stream of
+    """The Pallas scatter plan is bucketed like everything else: a stream of
     same-bucket problems with use_pallas=True shares ONE executable (cold
     once, warm after, no fallback) and stays array-identical to the
     unpadded decompose()."""
@@ -191,22 +191,22 @@ def test_use_pallas_rides_the_warm_path():
 
 
 def test_pallas_over_budget_plan_falls_back():
-    """A (megakernel) plan bigger than the VMEM-plan budget must take the
+    """A padded Pallas plan bigger than the plan budget must take the
     cold path, not die: the Session races plan bytes against
-    MEGAKERNEL_PLAN_BUDGET_BYTES before bucketing."""
+    PLAN_BUDGET_BYTES before bucketing."""
     from repro.core import session as session_mod
     problem = build_problem(GRAPHS["planted40"](), 2, 3)
     cfg = NucleusConfig(r=2, s=3, backend="dense", hierarchy="fused",
                         use_pallas=True)
-    old = session_mod.MEGAKERNEL_PLAN_BUDGET_BYTES
+    old = session_mod.PLAN_BUDGET_BYTES
     try:
-        session_mod.MEGAKERNEL_PLAN_BUDGET_BYTES = 1  # force over-budget
+        session_mod.PLAN_BUDGET_BYTES = 1  # force over-budget
         sess = Session(cfg)
         dec = sess.decompose(problem)
         assert sess.stats["fallback"] == 1
         _assert_same(dec, decompose(problem, cfg), "pallas-over-budget")
     finally:
-        session_mod.MEGAKERNEL_PLAN_BUDGET_BYTES = old
+        session_mod.PLAN_BUDGET_BYTES = old
 
 
 def test_fallback_preserves_auto_plan_provenance():
@@ -246,7 +246,7 @@ def test_plan_budget_gate_counts_padded_bytes():
     """Regression: the gate must race the PADDED plan footprint against
     the budget.  The old gate used unpadded sizes, so a problem whose
     pow2-padded member matrix landed over budget was still sent down the
-    megakernel path.  A budget between the two sizes must fall back."""
+    bucketed path.  A budget between the two sizes must fall back."""
     from repro.core import session as session_mod
     from repro.kernels.segment_sum import DEFAULT_CHUNK_E
     problem = build_problem(GRAPHS["planted40"](), 2, 3)
@@ -254,12 +254,13 @@ def test_plan_budget_gate_counts_padded_bytes():
     unpadded = 4 * problem.n_s * C * C
     padded = 4 * session_mod.bucket_size(problem.n_s * C,
                                          DEFAULT_CHUNK_E) * C
+    assert padded == session_mod.padded_plan_bytes(problem)
     assert unpadded < padded, "fixture must straddle the pad boundary"
     cfg = NucleusConfig(r=2, s=3, backend="dense", hierarchy="fused",
                         use_pallas=True)
-    old = session_mod.MEGAKERNEL_PLAN_BUDGET_BYTES
+    old = session_mod.PLAN_BUDGET_BYTES
     try:
-        session_mod.MEGAKERNEL_PLAN_BUDGET_BYTES = (unpadded + padded) // 2
+        session_mod.PLAN_BUDGET_BYTES = (unpadded + padded) // 2
         sess = Session(cfg)
         dec = sess.decompose(problem)
         assert sess.stats["fallback"] == 1, (
@@ -267,7 +268,7 @@ def test_plan_budget_gate_counts_padded_bytes():
             "cold path")
         _assert_same(dec, decompose(problem, cfg), "padded-gate")
     finally:
-        session_mod.MEGAKERNEL_PLAN_BUDGET_BYTES = old
+        session_mod.PLAN_BUDGET_BYTES = old
 
 
 def test_bucket_key_matches_plan_built_key():
