@@ -44,6 +44,7 @@ from .interleaved import (construct_tree_efficient, link_state_from_forest,
                           replay_trace)
 from .nuclei import edge_density, nucleus_vertex_sets
 from .peel import PeelResult
+from .trace import span
 
 JSON_FORMAT = "repro.nucleus-decomposition"
 JSON_VERSION = 2
@@ -248,6 +249,11 @@ class Decomposition:
     ``to_json()`` pins the artifact (tree materialized, inputs the queries
     need embedded), so ``from_json()`` serves queries with no
     ``NucleusProblem`` and no recomputation.
+
+    ``counters`` holds the peel's loop counts where the Session's padded
+    dense path ran it (``fixpoint_gens``, ``live_links``, ``link_slots``
+    and the unpadded and padded ``n_r``/``n_s``; PERF.md §3); None on
+    paths that do not count (sharded, fallback, streaming, JSON).
     """
 
     def __init__(self, config: NucleusConfig, *,
@@ -264,8 +270,12 @@ class Decomposition:
                  n_s: Optional[int] = None,
                  plan: Optional[Plan] = None,
                  name: Optional[str] = None,
-                 version: int = 0):
+                 version: int = 0,
+                 counters: Optional[Dict[str, int]] = None):
         self.config = config
+        self.counters = counters
+        # set by the serve.Router that produced it; its query spans repeat it
+        self.request_id: Optional[int] = None
         self._name = name
         self._version = int(version)
         self._plan = plan
@@ -374,6 +384,10 @@ class Decomposition:
         """The hierarchy tree, materialized on first access and cached."""
         if self._tree is not None:
             return self._tree
+        with span("tree", rid=self.request_id, n_r=self.n_r):
+            return self._build_tree()
+
+    def _build_tree(self) -> HierarchyTree:
         h = self.config.hierarchy
         if h == "none":
             raise ValueError(
@@ -438,7 +452,8 @@ class Decomposition:
         """
         c = int(c)
         if c not in self._cuts:
-            self._cuts[c] = self.tree.ancestor_at_level(c)
+            with span("cut", rid=self.request_id, c=c):
+                self._cuts[c] = self.tree.ancestor_at_level(c)
         return self._cuts[c]
 
     def nuclei(self, c: int) -> Dict[int, Nucleus]:
@@ -449,6 +464,11 @@ class Decomposition:
         c = int(c)
         if c in self._nuclei:
             return self._nuclei[c]
+        with span("nuclei", rid=self.request_id, c=c):
+            self._nuclei[c] = self._vertex_sets(c)
+        return self._nuclei[c]
+
+    def _vertex_sets(self, c: int) -> Dict[int, Nucleus]:
         labels = self.cut(c)
         rc = self._r_cliques if self._r_cliques is not None else (
             None if self.problem is None
@@ -472,7 +492,6 @@ class Decomposition:
             out[int(lab)] = Nucleus(label=int(lab), vertices=verts,
                                     n_r_cliques=int(counts[lab]),
                                     density=dens)
-        self._nuclei[c] = out
         return out
 
     # -- incremental maintenance -------------------------------------------

@@ -46,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..graph import INT
+from ..graph.connectivity import _varying
 from ..graph.unionfind import uf_union_edges
 from ..kernels.peel_round import (chunk_windows, fused_peel_round,
                                   peel_round_plan)
@@ -162,6 +163,11 @@ def link_fixpoint(parent, L, core, la, lb, lvalid, *, max_gens: int):
     argument (DESIGN.md §5): unions are bounded by n_r - 1 and every
     surviving successor strictly lowers core[target], so max_gens = O(n_r)
     generations suffice; the cap is a lowering bound, never binding.
+
+    Returns (parent, L, gens, live): ``gens`` is the generations the loop
+    ran and ``live`` the live worklist slots summed over them (the first
+    generation's are the valid input links).  The loop carries the live
+    count its condition tests, so counting adds no pass over the worklist.
     """
     n_r = parent.shape[0]
     K = la.shape[0]
@@ -174,11 +180,11 @@ def link_fixpoint(parent, L, core, la, lb, lvalid, *, max_gens: int):
     wv = jnp.concatenate([lvalid, jnp.zeros((n_r,), bool)])
 
     def cond(st):
-        _, _, _, _, wv, gen = st
-        return jnp.any(wv) & (gen < max_gens)
+        n_live, gen = st[5], st[6]
+        return (n_live > 0) & (gen < max_gens)
 
     def body(st):
-        parent, L, wa, wb, wv, gen = st
+        parent, L, wa, wb, wv, n_live, gen, live = st
         # resolve (parent is fully resolved: one gather) and orient
         a = parent[jnp.clip(wa, 0, n_r - 1)]
         b = parent[jnp.clip(wb, 0, n_r - 1)]
@@ -227,11 +233,15 @@ def link_fixpoint(parent, L, core, la, lb, lvalid, *, max_gens: int):
         wa = jnp.concatenate([na[:K], jnp.where(lost, L, na[K:])])
         wb = jnp.concatenate([nb[:K], jnp.where(lost, parent, nb[K:])])
         wv = jnp.concatenate([succ_v[:K], succ_v[K:] | lost])
-        return parent, newL, wa, wb, wv, gen + 1
+        return (parent, newL, wa, wb, wv, jnp.sum(wv, dtype=INT), gen + 1,
+                live + n_live)
 
-    parent, L, _, _, _, _ = jax.lax.while_loop(
-        cond, body, (parent, L, wa, wb, wv, jnp.zeros((), INT)))
-    return parent, L
+    zero = jnp.zeros((), INT)
+    n_live = jnp.sum(wv, dtype=INT)
+    parent, L, _, _, _, _, gens, live = jax.lax.while_loop(
+        cond, body, (parent, L, wa, wb, wv, n_live, zero,
+                     _varying(zero, n_live)))
+    return parent, L, gens, live
 
 
 # ---------------------------------------------------------------------------
@@ -377,10 +387,13 @@ def run_peel_engine(inc_rid, deg0, schedule: PeelSchedule, *,
                     peeled0=None):
     """Drive ``peel_round`` to a fixpoint under one ``lax.while_loop``.
 
-    Returns (core, order_round, rounds): raw bucket values per r-clique, the
-    on-device peel trace, and the round count.  Every round peels at least
-    one clique (the schedule guarantees level >= dmin), so the loop runs at
-    most n_r rounds; max_rounds is a static safety cap for lowering.
+    Returns (core, order_round, rounds, gens, live): raw bucket values per
+    r-clique, the on-device peel trace, the round count, and the link
+    fixpoint's generations and live worklist slots, each summed over the
+    rounds (``link_fixpoint``'s counts; zeros without hierarchy).  Every
+    round peels at least one clique (the schedule guarantees level >=
+    dmin), so the loop runs at most n_r rounds; max_rounds is a static
+    safety cap for lowering.
 
     hierarchy=True additionally threads the fused ANH-EL state through the
     carry and appends (parent, L) — the resolved same-core join forest — to
@@ -399,11 +412,12 @@ def run_peel_engine(inc_rid, deg0, schedule: PeelSchedule, *,
     n_r = deg0.shape[0]
     core0 = jnp.full((n_r,), -1, INT)
     order0 = jnp.full((n_r,), -1, INT)
+    zero = jnp.zeros((), INT)
     if n_r == 0:
         if hierarchy:
             empty = jnp.zeros((0,), INT)
-            return core0, order0, jnp.zeros((), INT), empty, empty
-        return core0, order0, jnp.zeros((), INT)
+            return core0, order0, zero, zero, zero, empty, empty
+        return core0, order0, zero, zero, zero
     peeled0 = jnp.zeros((n_r,), bool) if peeled0 is None else peeled0
     if alive0 is None:
         alive0 = jnp.ones((inc_rid.shape[0],), bool)
@@ -412,7 +426,10 @@ def run_peel_engine(inc_rid, deg0, schedule: PeelSchedule, *,
     if hierarchy and link0 is None:
         link0 = (jnp.arange(n_r, dtype=INT), jnp.full((n_r,), -1, INT),
                  jnp.full((inc_rid.shape[0],), -1, INT))
-    if not hierarchy:
+    if hierarchy:
+        # the fixpoint's counts ride with the link state, typed like it
+        link0 = tuple(link0) + (_varying(zero, link0[0]),) * 2
+    else:
         link0 = ()
     sched0 = schedule.init_carry()
     rounds0 = jnp.zeros((), INT)
@@ -429,31 +446,36 @@ def run_peel_engine(inc_rid, deg0, schedule: PeelSchedule, *,
 
     def body(carry):
         deg, peeled, alive, core, order, sched, rounds, resid = carry[:8]
-        deg, peeled, alive, core, order, sched, resid, a_mask = peel_round(
-            inc_rid, deg, peeled, alive, core, order, sched, rounds,
-            schedule, reduce_delta=reduce_delta, resid=resid,
-            scatter=scatter, fused_round=fused_round)
+        with jax.named_scope("repro.peel_round"):
+            deg, peeled, alive, core, order, sched, resid, a_mask = \
+                peel_round(inc_rid, deg, peeled, alive, core, order, sched,
+                           rounds, schedule, reduce_delta=reduce_delta,
+                           resid=resid, scatter=scatter,
+                           fused_round=fused_round)
         link = carry[8:]
         # no s-cliques -> no links ever; also keeps all_gather away from
         # zero-size operands (XLA rejects an empty all_gather dim)
         if hierarchy and inc_rid.shape[0] > 0:
-            parent, L, last = link
-            la, lb, lv, last = round_links(inc_rid, a_mask, last)
+            parent, L, last, gens, live = link
+            with jax.named_scope("repro.round_links"):
+                la, lb, lv, last = round_links(inc_rid, a_mask, last)
             if gather_links is not None:
                 la, lb, lv = gather_links(la, lb, lv)
-            parent, L = link_fixpoint(parent, L, core, la, lb, lv,
-                                      max_gens=max_gens)
-            link = (parent, L, last)
+            with jax.named_scope("repro.link_fixpoint"):
+                parent, L, g, n = link_fixpoint(parent, L, core, la, lb, lv,
+                                                max_gens=max_gens)
+            link = (parent, L, last, gens + g, live + n)
         return (deg, peeled, alive, core, order, sched, rounds + 1,
                 resid) + link
 
     carry = (deg0, peeled0, alive0, core0, order0, sched0, rounds0,
-             resid0) + tuple(link0)
+             resid0) + link0
     out = jax.lax.while_loop(cond, body, carry)
     core, order, rounds = out[3], out[4], out[6]
     if hierarchy:
-        return core, order, rounds, out[8], out[9]
-    return core, order, rounds
+        parent, L, _, gens, live = out[8:]
+        return core, order, rounds, gens, live, parent, L
+    return core, order, rounds, zero, zero
 
 
 # ---------------------------------------------------------------------------
@@ -478,7 +500,8 @@ def _dense_engine(inc_rid, deg0, plan_a, plan_b, peeled0, *,
     with fused=True: (plan_a, plan_b) = (ids, members) of the round
     megakernel — one Pallas launch replaces the whole select + gather +
     decrement chain.  spec set with fused=False: (plan_a, plan_b) =
-    (rids, sids) of the scatter-only Pallas path (the decrement alone)."""
+    (rids, sids) of the scatter-only Pallas path (the decrement alone).
+    Returns ``run_peel_engine``'s outputs."""
     n_r = deg0.shape[0]
     scatter = None
     fused_round = None
@@ -600,7 +623,10 @@ def dense_coreness(problem: NucleusProblem, schedule: PeelSchedule, *,
                    peeled0=None,
                    plan=None,
                    fused_kernel: Optional[bool] = None):
-    """One jitted call: (core_raw, order_round, rounds) for the whole peel.
+    """One jitted call: (core_raw, order_round, rounds, fixpoint_gens,
+    live_links) for the whole peel; the last two are the link fixpoint's
+    generations and live worklist slots summed over the rounds, both 0
+    without hierarchy (``run_peel_engine``).
 
     use_pallas=None resolves through ``pallas_by_default()`` — the planner
     profile's measured verdict when one covers this device, else Pallas on

@@ -46,6 +46,7 @@ from ..graph import (Graph, INT, csr_from_pairs, iter_clique_chunks,
 from ..graph.cliques import expand_levels, lexsort_rows, sort_join_np
 from ..graph.orientation import degree_rank, approx_degeneracy_rank
 from ..graph.container import Digraph, orient
+from .trace import span
 
 BUILDS = ("eager", "chunked", "sharded")
 # default memory budget for build="chunked" when the caller names neither a
@@ -130,21 +131,25 @@ def build_problem(g: Graph, r: int, s: int,
         raise ValueError(
             f"shards={shards} is the sharded builder's worker count; set "
             f"build='sharded' or drop it (got build={build!r})")
-    if build == "sharded":
-        if fastpath:
-            raise ValueError(
-                "fastpath=True is the chunked builder's dense (2,3) count "
-                "pass; it does not apply to build='sharded'")
-        from ..distbuild import build_problem_sharded
-        return build_problem_sharded(
-            g, r, s, rank, n_shards=shards,
-            memory_budget_bytes=memory_budget_bytes, chunk_size=chunk_size)
-    dg, orientation = _resolve_digraph(g, rank)
-    if build == "eager":
-        return _build_eager(g, r, s, dg, orientation)
-    return _build_chunked(g, r, s, dg, orientation,
-                          memory_budget_bytes=memory_budget_bytes,
-                          chunk_size=chunk_size, fastpath=fastpath)
+    if build == "sharded" and fastpath:
+        raise ValueError(
+            "fastpath=True is the chunked builder's dense (2,3) count "
+            "pass; it does not apply to build='sharded'")
+    with span("build", n=g.n, m=int(g.edges.shape[0]), r=r, s=s,
+              build=build):
+        if build == "sharded":
+            from ..distbuild import build_problem_sharded
+            return build_problem_sharded(
+                g, r, s, rank, n_shards=shards,
+                memory_budget_bytes=memory_budget_bytes,
+                chunk_size=chunk_size)
+        with span("build.orient"):
+            dg, orientation = _resolve_digraph(g, rank)
+        if build == "eager":
+            return _build_eager(g, r, s, dg, orientation)
+        return _build_chunked(g, r, s, dg, orientation,
+                              memory_budget_bytes=memory_budget_bytes,
+                              chunk_size=chunk_size, fastpath=fastpath)
 
 
 # ---------------------------------------------------------------------------
@@ -233,6 +238,22 @@ def _assemble(g: Graph, r: int, s: int, r_rows: np.ndarray,
     The sort-join and CSR fill are blocked by ``budget``; every step is a
     per-row pure function of the eager path's, so output is bit-identical.
     """
+    with span("build.assemble", n_r=int(r_rows.shape[0]),
+              n_s=int(s_rows.shape[0])):
+        r_table, inc, mem_offsets, mem_sids, deg0 = _assemble_host(
+            r, s, r_rows, s_rows, budget, stats)
+    with span("build.upload"):
+        return NucleusProblem(
+            g=g, r=r, s=s, r_cliques=jnp.asarray(r_table),
+            inc_rid=jnp.asarray(inc), mem_offsets=jnp.asarray(mem_offsets),
+            mem_sids=jnp.asarray(mem_sids), deg0=jnp.asarray(deg0),
+            orientation=orientation, build_stats=stats)
+
+
+def _assemble_host(r: int, s: int, r_rows: np.ndarray, s_rows: np.ndarray,
+                   budget: int, stats: Dict[str, Any]):
+    """``_assemble``'s numpy part: (r_table, inc, mem_offsets, mem_sids,
+    deg0) as host arrays."""
     C = comb(s, r)
     n_s = int(s_rows.shape[0])
     if r_rows.shape[0]:
@@ -279,11 +300,7 @@ def _assemble(g: Graph, r: int, s: int, r_rows: np.ndarray,
 
     stats["peak_intermediate_bytes"] = max(
         stats.get("peak_intermediate_bytes", 0), join_bytes)
-    return NucleusProblem(
-        g=g, r=r, s=s, r_cliques=jnp.asarray(r_table),
-        inc_rid=jnp.asarray(inc), mem_offsets=jnp.asarray(mem_offsets),
-        mem_sids=jnp.asarray(mem_sids), deg0=jnp.asarray(deg0),
-        orientation=orientation, build_stats=stats)
+    return r_table, inc, mem_offsets, mem_sids, deg0
 
 
 def _oriented_counts(dense: jnp.ndarray) -> jnp.ndarray:
@@ -356,16 +373,17 @@ def _build_chunked_23_dense(g: Graph, dg: Digraph, orientation: str,
     is unavailable.
     """
     n = dg.n
-    outdeg = np.asarray(dg.outdeg)
-    nbrs = np.asarray(dg.neighbors)
-    src = np.repeat(np.arange(n, dtype=np.int32), outdeg)
-    dense = np.zeros((n, n), np.float32)
-    if src.size:
-        dense[src, nbrs] = 1.0
-    counts_nn = np.asarray(_oriented_counts(jnp.asarray(dense)))
-    ext = counts_nn[src, nbrs].astype(np.int64) if src.size \
-        else np.zeros((0,), np.int64)
-    n_s = int(ext.sum())
+    with span("build.count", n=n):
+        outdeg = np.asarray(dg.outdeg)
+        nbrs = np.asarray(dg.neighbors)
+        src = np.repeat(np.arange(n, dtype=np.int32), outdeg)
+        dense = np.zeros((n, n), np.float32)
+        if src.size:
+            dense[src, nbrs] = 1.0
+        counts_nn = np.asarray(_oriented_counts(jnp.asarray(dense)))
+        ext = counts_nn[src, nbrs].astype(np.int64) if src.size \
+            else np.zeros((0,), np.int64)
+        n_s = int(ext.sum())
 
     # r-cliques = DAG edges in CSR (expansion) order, rows ascending
     r_rows = np.sort(np.stack([src, nbrs], axis=1), axis=1).astype(np.int32) \
@@ -376,18 +394,19 @@ def _build_chunked_23_dense(g: Graph, dg: Digraph, orientation: str,
     s_rows = np.empty((n_s, 3), np.int32)
     at = 0
     n_blocks = 0
-    for e0 in range(0, src.size, e_block):
-        u = src[e0:e0 + e_block]
-        v = nbrs[e0:e0 + e_block]
-        members = dense[u] * dense[v]  # (block, n) common out-neighbors
-        eidx, w = np.nonzero(members)  # row-major: edge order, w ascending
-        tri = np.stack([u[eidx].astype(np.int32),
-                        v[eidx].astype(np.int32),
-                        w.astype(np.int32)], axis=1)
-        tri.sort(axis=1)
-        s_rows[at:at + tri.shape[0]] = tri
-        at += tri.shape[0]
-        n_blocks += 1
+    with span("build.fill", n_s=n_s):
+        for e0 in range(0, src.size, e_block):
+            u = src[e0:e0 + e_block]
+            v = nbrs[e0:e0 + e_block]
+            members = dense[u] * dense[v]  # (block, n) common out-neighbors
+            eidx, w = np.nonzero(members)  # row-major: edge order, w asc.
+            tri = np.stack([u[eidx].astype(np.int32),
+                            v[eidx].astype(np.int32),
+                            w.astype(np.int32)], axis=1)
+            tri.sort(axis=1)
+            s_rows[at:at + tri.shape[0]] = tri
+            at += tri.shape[0]
+            n_blocks += 1
     assert at == n_s, (at, n_s)  # kernel counts must agree with the fill
 
     # the count stage held ~4 (n, n) f32 blocks live (np dense + jnp copy +

@@ -118,20 +118,21 @@ def _kcore_engine(vids, nbrs, edges, deg0, *, schedule: PeelSchedule,
         return deg, newp, core, order
 
     dummy_inc = jnp.zeros((0, 2), INT)
-    core, order, rounds = run_peel_engine(
-        dummy_inc, deg0, schedule, max_rounds=max_rounds,
-        fused_round=fused_round)
+    out = run_peel_engine(dummy_inc, deg0, schedule, max_rounds=max_rounds,
+                          fused_round=fused_round)
     if not hierarchy:
-        return core, order, rounds
+        return out
+    core, order, rounds = out[:3]
     # ONE fixpoint over the whole edge-list link multiset (see module
     # docstring): same (parent, L) as the per-round fused engine by the
     # confluence of link_fixpoint, at a single invocation's cost.
     parent0 = jnp.arange(n, dtype=INT)
     L0 = jnp.full((n,), -1, INT)
     lvalid = jnp.ones((edges.shape[0],), bool)
-    parent, L = link_fixpoint(parent0, L0, core, edges[:, 0], edges[:, 1],
-                              lvalid, max_gens=3 * n + 4)
-    return core, order, rounds, parent, L
+    parent, L, gens, live = link_fixpoint(parent0, L0, core, edges[:, 0],
+                                          edges[:, 1], lvalid,
+                                          max_gens=3 * n + 4)
+    return core, order, rounds, gens, live, parent, L
 
 
 def kcore_coreness(problem: NucleusProblem, schedule: PeelSchedule, *,
@@ -139,9 +140,11 @@ def kcore_coreness(problem: NucleusProblem, schedule: PeelSchedule, *,
                    hierarchy: bool = False):
     """Drop-in for ``dense_coreness`` on an (r, s) = (1, 2) problem.
 
-    Same return contract: (core_raw, order_round, rounds[, parent, L]),
-    bit-identical to the generic dense engine (and, for the hierarchy,
-    to the host replay oracle) — the golden tests pin both.
+    Same return contract: (core_raw, order_round, rounds, fixpoint_gens,
+    live_links[, parent, L]), the core, order and forest bit-identical to
+    the generic dense engine (and, for the hierarchy, to the host replay
+    oracle) — the golden tests pin both.  The counts are this lane's one
+    fixpoint's, over the whole edge list.
     """
     assert (problem.r, problem.s) == (1, 2), \
         f"kcore lane needs (r, s) = (1, 2), got {(problem.r, problem.s)}"
@@ -150,7 +153,8 @@ def kcore_coreness(problem: NucleusProblem, schedule: PeelSchedule, *,
         max_rounds = n + 2
     if n == 0:
         empty = jnp.zeros((0,), INT)
-        out = (empty, empty, jnp.zeros((), INT))
+        zero = jnp.zeros((), INT)
+        out = (empty, empty, zero, zero, zero)
         return out + (empty, empty) if hierarchy else out
     vids, nbrs = kcore_plan(problem)
     edges = jnp.asarray(problem.g.edges, INT).reshape(-1, 2)

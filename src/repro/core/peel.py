@@ -128,21 +128,14 @@ def _run(problem: NucleusProblem, schedule: PeelSchedule,
                 and not wants_pallas
         if fast_lane:
             out = kcore_coreness(problem, schedule, hierarchy=hierarchy)
-            if hierarchy:
-                core, order, rounds, parent, L = out
-                return PeelResult(core=core, rounds=int(rounds),
-                                  order_round=order, uf_parent=parent,
-                                  uf_L=L)
-            core, order, rounds = out
-            return PeelResult(core=core, rounds=int(rounds),
-                              order_round=order)
+        else:
+            out = dense_coreness(problem, schedule, use_pallas=use_pallas,
+                                 hierarchy=hierarchy)
+        core, order, rounds = out[:3]
         if hierarchy:
-            core, order, rounds, parent, L = dense_coreness(
-                problem, schedule, use_pallas=use_pallas, hierarchy=True)
             return PeelResult(core=core, rounds=int(rounds),
-                              order_round=order, uf_parent=parent, uf_L=L)
-        core, order, rounds = dense_coreness(problem, schedule,
-                                             use_pallas=use_pallas)
+                              order_round=order, uf_parent=out[5],
+                              uf_L=out[6])
         return PeelResult(core=core, rounds=int(rounds), order_round=order)
     res = _peel_loop(problem, schedule)
     if hierarchy:

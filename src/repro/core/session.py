@@ -62,6 +62,7 @@ from .engine import (ScatterSpec, _scatter_plan, dense_coreness,
                      pallas_by_default, round_up_pow2_chunks)
 from .incidence import NucleusProblem
 from .schedule import PeelSchedule
+from .trace import span
 
 DEFAULT_BUCKET_FLOOR = 64
 # ceiling on ``padded_plan_bytes`` for the bucketed path and for serving
@@ -232,7 +233,13 @@ class Session:
     def decompose(self, graph_or_problem) -> Decomposition:
         """Same contract (and bit-identical arrays) as
         ``api.decompose(graph_or_problem, self.config)``."""
-        problem, config = resolve_problem(graph_or_problem, self.config)
+        with span("session.decompose") as sp:
+            problem, config = resolve_problem(graph_or_problem, self.config)
+            sp.add(n_r=problem.n_r, n_s=problem.n_s)
+            return self._decompose(problem, config)
+
+    def _decompose(self, problem: NucleusProblem,
+                   config: NucleusConfig) -> Decomposition:
         config, plan = plan_config(problem, config)
         self._count("decompositions")
         # the padded path covers the compiled dense engine, XLA round body
@@ -503,12 +510,15 @@ class Session:
         warm = self._bucket_hit(key, meta=meta)
         self._count("warm" if warm else "cold")
 
-        inc = jnp.concatenate(
-            [problem.inc_rid, jnp.full((n_s_pad - n_s, C), -1, INT)], axis=0)
-        deg0 = jnp.concatenate(
-            [problem.deg0, jnp.zeros((n_r_pad - n_r,), INT)])
-        peeled0 = jnp.concatenate(
-            [jnp.zeros((n_r,), bool), jnp.ones((n_r_pad - n_r,), bool)])
+        with span("session.pad", n_r=n_r, n_s=n_s, n_r_pad=n_r_pad,
+                  n_s_pad=n_s_pad):
+            inc = jnp.concatenate(
+                [problem.inc_rid, jnp.full((n_s_pad - n_s, C), -1, INT)],
+                axis=0)
+            deg0 = jnp.concatenate(
+                [problem.deg0, jnp.zeros((n_r_pad - n_r,), INT)])
+            peeled0 = jnp.concatenate(
+                [jnp.zeros((n_r,), bool), jnp.ones((n_r_pad - n_r,), bool)])
         padded = _PaddedProblem(inc_rid=inc, deg0=deg0, n_r=n_r_pad,
                                 n_s=n_s_pad)
         kernel_plan = None
@@ -517,21 +527,30 @@ class Session:
             # bucket key came from the shape-derived spec twin, which must
             # agree with the real plan or warm members would miss the
             # executable the bucket promised
-            kernel_plan = self._pallas_plan(problem, n_r_pad)
+            with span("session.plan", e=int(problem.mem_sids.shape[0])):
+                kernel_plan = self._pallas_plan(problem, n_r_pad)
             assert kernel_plan[2] == bucket.pallas, (
                 "shape-derived ScatterSpec diverged from the real plan: "
                 f"{bucket.pallas} vs {kernel_plan[2]}")
-        out = dense_coreness(padded, sched,
-                             use_pallas=kernel_plan is not None,
-                             max_rounds=n_r_pad + 2, hierarchy=fused,
-                             peeled0=peeled0, plan=kernel_plan)
-        core_raw = np.asarray(out[0])[:n_r]
-        order_round = np.asarray(out[1])[:n_r]
-        rounds = int(out[2])
-        uf_parent = uf_L = None
-        if fused:
-            uf_parent = np.asarray(out[3])[:n_r]
-            uf_L = np.asarray(out[4])[:n_r]
+        sizes = {"n_r": n_r, "n_s": n_s, "n_r_pad": n_r_pad,
+                 "n_s_pad": n_s_pad}
+        with span("engine", fused=int(fused), **sizes) as engine:
+            out = dense_coreness(padded, sched,
+                                 use_pallas=kernel_plan is not None,
+                                 max_rounds=n_r_pad + 2, hierarchy=fused,
+                                 peeled0=peeled0, plan=kernel_plan)
+            with span("engine.fetch"):
+                core_raw = np.asarray(out[0])[:n_r]
+                order_round = np.asarray(out[1])[:n_r]
+                rounds, gens, live = (int(x) for x in out[2:5])
+                uf_parent = uf_L = None
+                if fused:
+                    uf_parent = np.asarray(out[5])[:n_r]
+                    uf_L = np.asarray(out[6])[:n_r]
+            # the worklist's width: n_s·C links + n_r handoffs
+            loop = {"fixpoint_gens": gens, "live_links": live,
+                    "link_slots": gens * (n_s_pad * C + n_r_pad)}
+            engine.add(rounds=rounds, **loop)
         if config.method == "approx":
             # same practical tightening as peel.approx_coreness: the
             # estimate never exceeds the original s-clique-degree, while
@@ -543,7 +562,8 @@ class Session:
         return Decomposition(config, problem=problem, core=core,
                              rounds=rounds, order_round=order_round,
                              peel_value=peel_value, uf_parent=uf_parent,
-                             uf_L=uf_L, plan=plan)
+                             uf_L=uf_L, plan=plan,
+                             counters={**loop, **sizes})
 
     # -- the padded sharded path -------------------------------------------
     def _decompose_padded_sharded(self, problem: NucleusProblem,

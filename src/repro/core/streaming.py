@@ -559,7 +559,7 @@ def _chains(inc: np.ndarray, core: np.ndarray):
 def _fixpoint_padded(parent0, L0, core, la, lb, lv):
     n = parent0.shape[0]
     return link_fixpoint(parent0, L0, core, la, lb, lv,
-                        max_gens=3 * n + 4)
+                         max_gens=3 * n + 4)[:2]
 
 
 def _run_fixpoint(parent0: np.ndarray, L0: np.ndarray, core: np.ndarray,

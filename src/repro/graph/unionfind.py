@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 
 from .connectivity import connected_components, pointer_jump
@@ -35,7 +36,8 @@ def uf_union_edges(parent: jnp.ndarray, u: jnp.ndarray,
 
     Self-edges are no-ops, so fixed-shape callers mask dead slots to (0, 0).
     """
-    return connected_components(int(parent.shape[0]), u, v, init=parent)
+    with jax.named_scope("repro.uf_union"):
+        return connected_components(int(parent.shape[0]), u, v, init=parent)
 
 
 @dataclasses.dataclass

@@ -155,4 +155,5 @@ def segment_sum_sorted(data: jnp.ndarray, ids: jnp.ndarray, n_segments: int,
         ),
         out_shape=jax.ShapeDtypeStruct((d, n_segments), data.dtype),
         interpret=interpret,
+        name="segment_sum_sorted",
     )(c0, nchunks, ids2d, data)

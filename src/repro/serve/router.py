@@ -30,6 +30,7 @@ import dataclasses
 import threading
 from typing import Any, Dict, List, Optional, Tuple
 
+from ..core import trace
 from ..core.api import (Decomposition, NucleusConfig, plan_config,
                         resolve_problem)
 from ..core.incidence import NucleusProblem
@@ -149,13 +150,19 @@ class Router:
     def route(self, request: Request) -> Decomposition:
         """Execute one request on its pool: decompose (publishing under
         ``request.artifact`` if named) or update-in-place of a named live
-        artifact."""
-        if request.kind == "update":
-            return self.update(request.artifact, request.update)
-        problem, config = self.resolve(request)
-        sess = self.pool(config)
-        dec = sess.decompose(problem)
-        self._record(config, dec, request.artifact)
+        artifact.  The request gets a fresh id (``core.trace``): its
+        ``repro.route`` span and every span under it carry it, and so do
+        the query spans of the returned artifact."""
+        rid = trace.new_request()
+        with trace.span("route", rid=rid, kind=request.kind):
+            if request.kind == "update":
+                dec = self.update(request.artifact, request.update)
+            else:
+                problem, config = self.resolve(request)
+                sess = self.pool(config)
+                dec = sess.decompose(problem)
+                self._record(config, dec, request.artifact)
+        dec.request_id = rid
         return dec
 
     def route_many(self, requests: List[Request],

@@ -203,8 +203,10 @@ def test_megakernel_full_peel_bit_identical(gname, r, s, mode, kernel):
     got = dense_coreness(p, sched, use_pallas=True, hierarchy=True,
                          interpret=True,
                          fused_kernel=kernel == "megakernel")
-    for f, a, b in zip(("core", "order_round", "rounds", "uf_parent",
-                        "uf_L"), ref, got):
+    names = ("core", "order_round", "rounds", "fixpoint_gens", "live_links",
+             "uf_parent", "uf_L")
+    assert len(ref) == len(got) == len(names)
+    for f, a, b in zip(names, ref, got):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                       err_msg=f)
 
