@@ -251,9 +251,10 @@ class Decomposition:
     ``NucleusProblem`` and no recomputation.
 
     ``counters`` holds the peel's loop counts where the Session's padded
-    dense path ran it (``fixpoint_gens``, ``live_links``, ``link_slots``
-    and the unpadded and padded ``n_r``/``n_s``; PERF.md §3); None on
-    paths that do not count (sharded, fallback, streaming, JSON).
+    dense path ran it (``fixpoint_gens``, ``live_links``, ``link_slots``,
+    ``link_refills`` and the unpadded and padded ``n_r``/``n_s``; PERF.md
+    §3); None on paths that do not count (sharded, fallback, streaming,
+    JSON).
     """
 
     def __init__(self, config: NucleusConfig, *,

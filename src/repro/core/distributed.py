@@ -161,13 +161,13 @@ def make_sharded_decomposition(mesh: Mesh, n_r: int, n_s_padded: int, C: int,
             link0 = (_varying(jnp.arange(n_r, dtype=INT), axis_names),
                      _varying(jnp.full((n_r,), -1, INT), axis_names),
                      _varying(jnp.full((n_s_local,), -1, INT), axis_names))
-            core, _order, rounds, _gens, _live, parent, L = run_peel_engine(
+            core, _order, rounds, _counts, parent, L = run_peel_engine(
                 inc_local, deg0, schedule, max_rounds=cap_rounds,
                 reduce_delta=reduce_delta, resid0=resid0, alive0=alive0,
                 hierarchy=True, link0=link0, gather_links=gather_links,
                 peeled0=peeled0)
             return core, rounds, replicate(parent), replicate(L)
-        core, _order, rounds, _gens, _live = run_peel_engine(
+        core, _order, rounds, _counts = run_peel_engine(
             inc_local, deg0, schedule, max_rounds=cap_rounds,
             reduce_delta=reduce_delta, resid0=resid0, alive0=alive0,
             peeled0=peeled0)

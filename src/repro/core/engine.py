@@ -57,6 +57,8 @@ from .incidence import NucleusProblem
 from .schedule import PeelSchedule
 
 BIG = np.iinfo(np.int32).max
+# the link fixpoint's counts, in the order of its ``counts`` vector
+FIXPOINT_COUNTS = ("fixpoint_gens", "live_links", "link_refills")
 
 
 def make_schedule(problem: NucleusProblem, kind: str,
@@ -139,52 +141,77 @@ def round_links(inc_rid, a_mask, last_peeled):
     return la, lb, lvalid, last_peeled
 
 
-def link_fixpoint(parent, L, core, la, lb, lvalid, *, max_gens: int):
+def fixpoint_width(n_r: int, n_links: int) -> int:
+    """The link fixpoint's worklist width: one slot per r-clique, never
+    more than the links it is handed.  A generation already does n_r-wide
+    work (``parent``, ``L``, the best tables), and its live count never
+    rises (DESIGN.md §5), so n_r slots hold every generation's successors
+    and handoffs; links beyond them wait in the pending list."""
+    return min(n_r, n_links)
+
+
+def link_fixpoint(parent, L, core, la, lb, lvalid, *,
+                  max_gens: Optional[int] = None,
+                  _width: Optional[int] = None):
     """Batched LINK-EFFICIENT fixpoint over one round's links, pure jnp.
 
     The numpy worklist (``interleaved.LinkState.process_links``) with fixed
-    shapes: the worklist has K + n_r slots (K initial links; one handoff
-    slot per r-clique).  Each generation
+    shapes.  The valid input links are compacted, in order, into a pending
+    list; the worklist has ``fixpoint_width(n_r, K)`` slots (``_width``
+    stands in for n_r, for tests) and takes pending links into its free
+    slots, first at the start and again after every generation.  Each
+    generation
 
       1. resolves + orients every link so core[a] <= core[b];
       2. unions same-core links with min-hooking (``uf_union_edges``, dead
          slots masked to self-edges), keeping ``parent`` fully resolved;
-      3. roots absorbed by the union hand their L off as a fresh link in
-         their node's handoff slot (each node loses root status at most
-         once ever, so the slot is collision-free);
-      4. lower-core links compete for L[target] by (max core, min id) via a
+      3. lower-core links compete for L[target] by (max core, min id) via a
          two-pass scatter; every losing candidate re-links against the
          winner *in place* (slot i's successor overwrites slot i), and the
-         ousted previous L re-links from the winning slot.
+         ousted previous L re-links from the winning slot;
+      4. roots absorbed by the union hand their L off as a fresh link
+         (L[j], new root) into the free slots, placed by a cumsum over the
+         losers and one over the free slots; pending links fill the rest.
 
-    Final (parent, L) is the same resolved state the host replay computes:
-    min-hooking and the (max core, min id) winner rule are confluent, so the
-    result depends only on the link multiset, not on slot order.  Progress
-    argument (DESIGN.md §5): unions are bounded by n_r - 1 and every
-    surviving successor strictly lowers core[target], so max_gens = O(n_r)
-    generations suffice; the cap is a lowering bound, never binding.
+    A generation's successors and handoffs never outnumber its live links
+    (a handoff needs a merge, a merge an equal-core link), so the handoffs
+    always fit.  Refilling only changes the arrival order of the same link
+    multiset, and min-hooking and the (max core, min id) winner rule are
+    confluent over it, so the final (parent, L) is the same resolved state
+    the host replay computes.  Progress argument (DESIGN.md §5): unions are
+    bounded by n_r - 1, every surviving successor strictly lowers
+    core[target], and a generation that ends with nothing live is followed
+    by one that takes at least one pending link, so the default max_gens
+    (3·n_r + 4 + K) is a lowering bound that never binds.
 
-    Returns (parent, L, gens, live): ``gens`` is the generations the loop
-    ran and ``live`` the live worklist slots summed over them (the first
-    generation's are the valid input links).  The loop carries the live
-    count its condition tests, so counting adds no pass over the worklist.
+    Returns (parent, L, counts), ``counts`` the int vector named by
+    ``FIXPOINT_COUNTS``: the generations the loop ran, the live worklist
+    slots summed over them, and the generations after the first that took
+    pending links.  The loop carries the live count its condition tests,
+    so counting adds no pass over the worklist.
     """
     n_r = parent.shape[0]
     K = la.shape[0]
-    W = K + n_r
+    if n_r == 0 or K == 0:
+        return parent, L, jnp.zeros((len(FIXPOINT_COUNTS),), INT)
+    W = fixpoint_width(n_r if _width is None else _width, K)
+    if max_gens is None:
+        max_gens = 3 * n_r + 4 + K
     node = jnp.arange(n_r, dtype=INT)
     idx = jnp.arange(W, dtype=INT)
-    zpad = jnp.zeros((n_r,), INT)
-    wa = jnp.concatenate([la, zpad])
-    wb = jnp.concatenate([lb, zpad])
-    wv = jnp.concatenate([lvalid, jnp.zeros((n_r,), bool)])
+    # -- pending list: the valid links' slots, in order, in one cumsum +
+    #    scatter
+    n_pend = jnp.sum(lvalid, dtype=INT)
+    dst = jnp.where(lvalid, jnp.cumsum(lvalid, dtype=INT) - 1, K)
+    pend = jnp.zeros((K,), INT).at[dst].set(jnp.arange(K, dtype=INT),
+                                            mode="drop")
 
     def cond(st):
-        n_live, gen = st[5], st[6]
+        n_live, gen = st[6], st[7]
         return (n_live > 0) & (gen < max_gens)
 
     def body(st):
-        parent, L, wa, wb, wv, n_live, gen, live = st
+        parent, L, wa, wb, wv, taken, n_live, gen, live, refills = st
         # resolve (parent is fully resolved: one gather) and orient
         a = parent[jnp.clip(wa, 0, n_r - 1)]
         b = parent[jnp.clip(wb, 0, n_r - 1)]
@@ -227,21 +254,36 @@ def link_fixpoint(parent, L, core, la, lb, lvalid, *, max_gens: int):
         host = is_win & (idx == rep[tgt])
         succ_a = jnp.where(host, Lc[jnp.clip(tgt, 0, n_r - 1)], cv)
         succ_v = lt & (succ_a >= 0) & (succ_a != w_t)
-        na = jnp.where(succ_v, succ_a, 0)
-        nb = jnp.where(succ_v, w_t, 0)
-        # -- handoff slots: node j's slot is K + j (free until j loses)
-        wa = jnp.concatenate([na[:K], jnp.where(lost, L, na[K:])])
-        wb = jnp.concatenate([nb[:K], jnp.where(lost, parent, nb[K:])])
-        wv = jnp.concatenate([succ_v[:K], succ_v[K:] | lost])
-        return (parent, newL, wa, wb, wv, jnp.sum(wv, dtype=INT), gen + 1,
-                live + n_live)
+        # -- free slots take the handoffs (L[j], parent[j]) of the losers
+        #    in node order, then pending links in list order
+        free = ~succ_v
+        rank = jnp.cumsum(free, dtype=INT) - 1
+        n_lost = jnp.sum(lost, dtype=INT)
+        lost_at = jnp.where(lost, jnp.cumsum(lost, dtype=INT) - 1, W)
+        lost_id = jnp.zeros((W,), INT).at[lost_at].set(node, mode="drop")
+        j = lost_id[jnp.clip(rank, 0, W - 1)]
+        hand = free & (rank < n_lost)
+        src = taken + rank - n_lost
+        take = free & ~hand & (src < n_pend)
+        s = pend[jnp.clip(src, 0, K - 1)]
+        wa = jnp.where(hand, L[j], jnp.where(take, la[s], succ_a))
+        wb = jnp.where(hand, parent[j], jnp.where(take, lb[s], w_t))
+        wv = succ_v | hand | take
+        n_take = jnp.sum(take, dtype=INT)
+        return (parent, newL, wa, wb, wv, taken + n_take,
+                jnp.sum(wv, dtype=INT), gen + 1, live + n_live,
+                refills + (n_take > 0))
 
-    zero = jnp.zeros((), INT)
-    n_live = jnp.sum(wv, dtype=INT)
-    parent, L, _, _, _, _, gens, live = jax.lax.while_loop(
-        cond, body, (parent, L, wa, wb, wv, n_live, zero,
-                     _varying(zero, n_live)))
-    return parent, L, gens, live
+    # the round's first fill; every carry typed like the data it meets
+    wv = idx < n_pend
+    first = pend[:W]
+    st = (parent, L, la[first], lb[first], wv, jnp.minimum(n_pend, W),
+          jnp.sum(wv, dtype=INT), jnp.zeros((), INT), jnp.zeros((), INT),
+          jnp.zeros((), INT))
+    st = tuple(_varying(x, parent, L, core, la, lb, pend) for x in st)
+    parent, L, _, _, _, _, _, gens, live, refills = jax.lax.while_loop(
+        cond, body, st)
+    return parent, L, jnp.stack([gens, live, refills])
 
 
 # ---------------------------------------------------------------------------
@@ -384,13 +426,13 @@ def run_peel_engine(inc_rid, deg0, schedule: PeelSchedule, *,
                     fused_round: Optional[Callable] = None,
                     hierarchy: bool = False, link0=None,
                     gather_links: Optional[Callable] = None,
-                    peeled0=None):
+                    peeled0=None, link_width: Optional[int] = None):
     """Drive ``peel_round`` to a fixpoint under one ``lax.while_loop``.
 
-    Returns (core, order_round, rounds, gens, live): raw bucket values per
+    Returns (core, order_round, rounds, counts): raw bucket values per
     r-clique, the on-device peel trace, the round count, and the link
-    fixpoint's generations and live worklist slots, each summed over the
-    rounds (``link_fixpoint``'s counts; zeros without hierarchy).  Every
+    fixpoint's ``counts`` vector (``FIXPOINT_COUNTS``) summed over the
+    rounds (zeros without hierarchy).  Every
     round peels at least one clique (the schedule guarantees level >=
     dmin), so the loop runs at most n_r rounds; max_rounds is a static
     safety cap for lowering.
@@ -401,7 +443,8 @@ def run_peel_engine(inc_rid, deg0, schedule: PeelSchedule, *,
     overrides the initial (parent, L, last_peeled) triple (the distributed
     backend passes device-varying-marked arrays); gather_links(la, lb,
     lvalid) all-gathers each round's locally generated links so the
-    replicated link state sees the global multiset.
+    replicated link state sees the global multiset.  link_width forces
+    the fixpoint's worklist width (``link_fixpoint``'s ``_width``; tests).
 
     peeled0 marks r-cliques as already peeled before round 0 — the ghost
     entries of a shape-bucketed padded problem (``core.session``).  They
@@ -413,11 +456,12 @@ def run_peel_engine(inc_rid, deg0, schedule: PeelSchedule, *,
     core0 = jnp.full((n_r,), -1, INT)
     order0 = jnp.full((n_r,), -1, INT)
     zero = jnp.zeros((), INT)
+    counts0 = jnp.zeros((len(FIXPOINT_COUNTS),), INT)
     if n_r == 0:
         if hierarchy:
             empty = jnp.zeros((0,), INT)
-            return core0, order0, zero, zero, zero, empty, empty
-        return core0, order0, zero, zero, zero
+            return core0, order0, zero, counts0, empty, empty
+        return core0, order0, zero, counts0
     peeled0 = jnp.zeros((n_r,), bool) if peeled0 is None else peeled0
     if alive0 is None:
         alive0 = jnp.ones((inc_rid.shape[0],), bool)
@@ -428,17 +472,11 @@ def run_peel_engine(inc_rid, deg0, schedule: PeelSchedule, *,
                  jnp.full((inc_rid.shape[0],), -1, INT))
     if hierarchy:
         # the fixpoint's counts ride with the link state, typed like it
-        link0 = tuple(link0) + (_varying(zero, link0[0]),) * 2
+        link0 = tuple(link0) + (_varying(counts0, link0[0]),)
     else:
         link0 = ()
     sched0 = schedule.init_carry()
     rounds0 = jnp.zeros((), INT)
-    # every generation consumes one of three finite budgets — a union
-    # (≤ n_r - 1 total), a handoff re-entry (≤ 1 per node), or a relink
-    # whose target core strictly drops (≤ n_r distinct values per chain) —
-    # so 3·n_r generations always suffice; the cap is a static lowering
-    # bound for the while_loop, never binding
-    max_gens = 3 * n_r + 4
 
     def cond(carry):
         peeled, rounds = carry[1], carry[6]
@@ -456,15 +494,15 @@ def run_peel_engine(inc_rid, deg0, schedule: PeelSchedule, *,
         # no s-cliques -> no links ever; also keeps all_gather away from
         # zero-size operands (XLA rejects an empty all_gather dim)
         if hierarchy and inc_rid.shape[0] > 0:
-            parent, L, last, gens, live = link
+            parent, L, last, counts = link
             with jax.named_scope("repro.round_links"):
                 la, lb, lv, last = round_links(inc_rid, a_mask, last)
             if gather_links is not None:
                 la, lb, lv = gather_links(la, lb, lv)
             with jax.named_scope("repro.link_fixpoint"):
-                parent, L, g, n = link_fixpoint(parent, L, core, la, lb, lv,
-                                                max_gens=max_gens)
-            link = (parent, L, last, gens + g, live + n)
+                parent, L, c = link_fixpoint(parent, L, core, la, lb, lv,
+                                             _width=link_width)
+            link = (parent, L, last, counts + c)
         return (deg, peeled, alive, core, order, sched, rounds + 1,
                 resid) + link
 
@@ -473,9 +511,9 @@ def run_peel_engine(inc_rid, deg0, schedule: PeelSchedule, *,
     out = jax.lax.while_loop(cond, body, carry)
     core, order, rounds = out[3], out[4], out[6]
     if hierarchy:
-        parent, L, _, gens, live = out[8:]
-        return core, order, rounds, gens, live, parent, L
-    return core, order, rounds, zero, zero
+        parent, L, _, counts = out[8:]
+        return core, order, rounds, counts, parent, L
+    return core, order, rounds, counts0
 
 
 # ---------------------------------------------------------------------------
@@ -491,16 +529,17 @@ def round_up_pow2_chunks(max_chunks: int, n_chunks: int) -> int:
 
 
 @partial(jax.jit, static_argnames=("schedule", "max_rounds", "spec",
-                                   "hierarchy", "fused"))
+                                   "hierarchy", "fused", "link_width"))
 def _dense_engine(inc_rid, deg0, plan_a, plan_b, peeled0, *,
                   schedule: PeelSchedule, max_rounds: int,
                   spec: Optional[ScatterSpec], hierarchy: bool = False,
-                  fused: bool = False):
+                  fused: bool = False, link_width: Optional[int] = None):
     """The jitted dense entry.  spec=None: pure-XLA round body.  spec set
     with fused=True: (plan_a, plan_b) = (ids, members) of the round
     megakernel — one Pallas launch replaces the whole select + gather +
     decrement chain.  spec set with fused=False: (plan_a, plan_b) =
     (rids, sids) of the scatter-only Pallas path (the decrement alone).
+    link_width forces the link fixpoint's worklist width (tests).
     Returns ``run_peel_engine``'s outputs."""
     n_r = deg0.shape[0]
     scatter = None
@@ -538,7 +577,8 @@ def _dense_engine(inc_rid, deg0, plan_a, plan_b, peeled0, *,
             return out[0, :n_r]
     return run_peel_engine(inc_rid, deg0, schedule, max_rounds=max_rounds,
                            scatter=scatter, fused_round=fused_round,
-                           hierarchy=hierarchy, peeled0=peeled0)
+                           hierarchy=hierarchy, peeled0=peeled0,
+                           link_width=link_width)
 
 
 def _scatter_plan(problem: NucleusProblem, block_n: int, chunk_e: int,
@@ -623,10 +663,10 @@ def dense_coreness(problem: NucleusProblem, schedule: PeelSchedule, *,
                    peeled0=None,
                    plan=None,
                    fused_kernel: Optional[bool] = None):
-    """One jitted call: (core_raw, order_round, rounds, fixpoint_gens,
-    live_links) for the whole peel; the last two are the link fixpoint's
-    generations and live worklist slots summed over the rounds, both 0
-    without hierarchy (``run_peel_engine``).
+    """One jitted call: (core_raw, order_round, rounds, counts) for the
+    whole peel; ``counts`` is the link fixpoint's ``FIXPOINT_COUNTS``
+    vector summed over the rounds, zeros without hierarchy
+    (``run_peel_engine``).
 
     use_pallas=None resolves through ``pallas_by_default()`` — the planner
     profile's measured verdict when one covers this device, else Pallas on

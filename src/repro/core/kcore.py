@@ -46,7 +46,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..graph import INT
-from .engine import h_index_rows, link_fixpoint, run_peel_engine
+from .engine import (FIXPOINT_COUNTS, h_index_rows, link_fixpoint,
+                     run_peel_engine)
 from .incidence import NucleusProblem
 from .schedule import PeelSchedule
 
@@ -101,9 +102,11 @@ def kcore_plan(problem: NucleusProblem):
     return plan
 
 
-@partial(jax.jit, static_argnames=("schedule", "max_rounds", "hierarchy"))
+@partial(jax.jit, static_argnames=("schedule", "max_rounds", "hierarchy",
+                                   "link_width"))
 def _kcore_engine(vids, nbrs, edges, deg0, *, schedule: PeelSchedule,
-                  max_rounds: int, hierarchy: bool):
+                  max_rounds: int, hierarchy: bool,
+                  link_width: Optional[int] = None):
     n = deg0.shape[0]
     def fused_round(deg, peeled, core, order, level, rnd):
         a = (~peeled) & (deg <= level)
@@ -129,10 +132,9 @@ def _kcore_engine(vids, nbrs, edges, deg0, *, schedule: PeelSchedule,
     parent0 = jnp.arange(n, dtype=INT)
     L0 = jnp.full((n,), -1, INT)
     lvalid = jnp.ones((edges.shape[0],), bool)
-    parent, L, gens, live = link_fixpoint(parent0, L0, core, edges[:, 0],
-                                          edges[:, 1], lvalid,
-                                          max_gens=3 * n + 4)
-    return core, order, rounds, gens, live, parent, L
+    parent, L, counts = link_fixpoint(parent0, L0, core, edges[:, 0],
+                                      edges[:, 1], lvalid, _width=link_width)
+    return core, order, rounds, counts, parent, L
 
 
 def kcore_coreness(problem: NucleusProblem, schedule: PeelSchedule, *,
@@ -140,11 +142,11 @@ def kcore_coreness(problem: NucleusProblem, schedule: PeelSchedule, *,
                    hierarchy: bool = False):
     """Drop-in for ``dense_coreness`` on an (r, s) = (1, 2) problem.
 
-    Same return contract: (core_raw, order_round, rounds, fixpoint_gens,
-    live_links[, parent, L]), the core, order and forest bit-identical to
-    the generic dense engine (and, for the hierarchy, to the host replay
-    oracle) — the golden tests pin both.  The counts are this lane's one
-    fixpoint's, over the whole edge list.
+    Same return contract: (core_raw, order_round, rounds, counts[, parent,
+    L]), the core, order and forest bit-identical to the generic dense
+    engine (and, for the hierarchy, to the host replay oracle) — the
+    golden tests pin both.  The counts are this lane's one fixpoint's,
+    over the whole edge list (n slots wide, so about m / n fills).
     """
     assert (problem.r, problem.s) == (1, 2), \
         f"kcore lane needs (r, s) = (1, 2), got {(problem.r, problem.s)}"
@@ -153,8 +155,8 @@ def kcore_coreness(problem: NucleusProblem, schedule: PeelSchedule, *,
         max_rounds = n + 2
     if n == 0:
         empty = jnp.zeros((0,), INT)
-        zero = jnp.zeros((), INT)
-        out = (empty, empty, zero, zero, zero)
+        out = (empty, empty, jnp.zeros((), INT),
+               jnp.zeros((len(FIXPOINT_COUNTS),), INT))
         return out + (empty, empty) if hierarchy else out
     vids, nbrs = kcore_plan(problem)
     edges = jnp.asarray(problem.g.edges, INT).reshape(-1, 2)

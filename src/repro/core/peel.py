@@ -134,8 +134,8 @@ def _run(problem: NucleusProblem, schedule: PeelSchedule,
         core, order, rounds = out[:3]
         if hierarchy:
             return PeelResult(core=core, rounds=int(rounds),
-                              order_round=order, uf_parent=out[5],
-                              uf_L=out[6])
+                              order_round=order, uf_parent=out[4],
+                              uf_L=out[5])
         return PeelResult(core=core, rounds=int(rounds), order_round=order)
     res = _peel_loop(problem, schedule)
     if hierarchy:

@@ -58,8 +58,9 @@ from ..graph import INT
 from ..kernels.segment_sum import DEFAULT_BLOCK_N, DEFAULT_CHUNK_E
 from .api import (Decomposition, NucleusConfig, execute_plan, plan_config,
                   resolve_problem)
-from .engine import (ScatterSpec, _scatter_plan, dense_coreness,
-                     pallas_by_default, round_up_pow2_chunks)
+from .engine import (FIXPOINT_COUNTS, ScatterSpec, _scatter_plan,
+                     dense_coreness, fixpoint_width, pallas_by_default,
+                     round_up_pow2_chunks)
 from .incidence import NucleusProblem
 from .schedule import PeelSchedule
 from .trace import span
@@ -542,14 +543,16 @@ class Session:
             with span("engine.fetch"):
                 core_raw = np.asarray(out[0])[:n_r]
                 order_round = np.asarray(out[1])[:n_r]
-                rounds, gens, live = (int(x) for x in out[2:5])
+                rounds = int(out[2])
+                loop = dict(zip(FIXPOINT_COUNTS,
+                                (int(x) for x in np.asarray(out[3]))))
                 uf_parent = uf_L = None
                 if fused:
-                    uf_parent = np.asarray(out[5])[:n_r]
-                    uf_L = np.asarray(out[6])[:n_r]
-            # the worklist's width: n_s·C links + n_r handoffs
-            loop = {"fixpoint_gens": gens, "live_links": live,
-                    "link_slots": gens * (n_s_pad * C + n_r_pad)}
+                    uf_parent = np.asarray(out[4])[:n_r]
+                    uf_L = np.asarray(out[5])[:n_r]
+            # every generation passes the whole worklist, fixed per bucket
+            loop["link_slots"] = loop["fixpoint_gens"] * fixpoint_width(
+                n_r_pad, n_s_pad * C)
             engine.add(rounds=rounds, **loop)
         if config.method == "approx":
             # same practical tightening as peel.approx_coreness: the

@@ -42,6 +42,7 @@ decompose of the edited graph under randomized insert/delete sequences.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Callable, Iterator, Optional, Tuple
 
 import jax
@@ -555,11 +556,11 @@ def _chains(inc: np.ndarray, core: np.ndarray):
     return mem[:, :-1].reshape(-1), mem[:, 1:].reshape(-1)
 
 
-@jax.jit
-def _fixpoint_padded(parent0, L0, core, la, lb, lv):
-    n = parent0.shape[0]
+@partial(jax.jit, static_argnames="link_width")
+def _fixpoint_padded(parent0, L0, core, la, lb, lv, *,
+                     link_width: Optional[int] = None):
     return link_fixpoint(parent0, L0, core, la, lb, lv,
-                         max_gens=3 * n + 4)[:2]
+                         _width=link_width)[:2]
 
 
 def _run_fixpoint(parent0: np.ndarray, L0: np.ndarray, core: np.ndarray,

@@ -7,10 +7,13 @@ replay must reproduce the callback-era interleaved hierarchy (join levels are
 the canonical comparison metric, matching the two-phase ANH-TE tree which
 the seed interleaved tests already pin down).
 """
+from functools import partial
+
 import numpy as np
 import pytest
 
 from repro.graph import generators
+from repro.core import engine, kcore
 from repro.core import (build_problem, replay_trace,
                         construct_tree_efficient, link_state_from_forest)
 from repro.core.peel import exact_coreness, approx_coreness
@@ -126,15 +129,33 @@ def test_trace_replay_equals_direct_replay(gname, r, s):
                                   t_g.join_levels(pairs))
 
 
+def forest_cases():
+    """Every problem at the fixpoint's own width, plus the (2,3) problems
+    and the k-core lane (ba60, r1s2) with the worklist forced to 8 slots,
+    so that pending links refill it and handoffs are compacted."""
+    for param in problems():
+        yield pytest.param(*param.values, None, id=param.id)
+    for gname in ("er30", "planted", "ba60", "fig1"):
+        yield pytest.param(gname, 2, 3, 8, id=f"{gname}-r2s3-w8")
+    yield pytest.param("ba60", 1, 2, 8, id="ba60-r1s2-w8")
+
+
 @pytest.mark.parametrize("mode", ["exact", "approx"])
-@pytest.mark.parametrize("gname,r,s", problems())
-def test_fused_forest_matches_replay_oracle(gname, r, s, mode):
+@pytest.mark.parametrize("gname,r,s,width", forest_cases())
+def test_fused_forest_matches_replay_oracle(gname, r, s, width, mode,
+                                            monkeypatch):
     """The on-device LINK fixpoint (hierarchy=True: uf/L threaded through
     the compiled peel carry) must reproduce the host trace-replay state
-    EXACTLY — same resolved parents, same L at every root, same tree."""
+    EXACTLY — same resolved parents, same L at every root, same tree —
+    whatever the worklist's width."""
     p = build_problem(GRAPHS[gname], r, s)
     if p.n_r == 0:
         pytest.skip("no r-cliques")
+    if width is not None:
+        monkeypatch.setattr(engine, "_dense_engine", partial(
+            engine._dense_engine, link_width=width))
+        monkeypatch.setattr(kcore, "_kcore_engine", partial(
+            kcore._kcore_engine, link_width=width))
     peel = (exact_coreness if mode == "exact"
             else lambda q, **kw: approx_coreness(q, delta=0.1, **kw))
     res = peel(p, backend="dense", hierarchy=True)
@@ -203,7 +224,7 @@ def test_megakernel_full_peel_bit_identical(gname, r, s, mode, kernel):
     got = dense_coreness(p, sched, use_pallas=True, hierarchy=True,
                          interpret=True,
                          fused_kernel=kernel == "megakernel")
-    names = ("core", "order_round", "rounds", "fixpoint_gens", "live_links",
+    names = ("core", "order_round", "rounds", "fixpoint_counts",
              "uf_parent", "uf_L")
     assert len(ref) == len(got) == len(names)
     for f, a, b in zip(names, ref, got):

@@ -13,10 +13,12 @@ Contracts:
     hierarchies, and problem-less (deserialized) artifacts fail with
     actionable messages instead of corrupting state.
 """
+from functools import partial
+
 import numpy as np
 import pytest
 
-from repro.core import GraphDelta, NucleusConfig, decompose
+from repro.core import GraphDelta, NucleusConfig, decompose, streaming
 from repro.core.streaming import SUPPORTED_RS
 from repro.graph import make_graph
 from repro.graph.generators import golden_suite
@@ -132,8 +134,19 @@ def test_update_parity_randomized(gname, r, s):
         _assert_matches_fresh(dec, cfg, f"{gname} r{r}s{s} step{step}")
 
 
-@pytest.mark.parametrize("r,s", sorted(SUPPORTED_RS))
-def test_update_batched_delta_mixed_ops(r, s):
+def mixed_cases():
+    """Each supported (r, s), plus (2, 3) with the forest patch's link
+    fixpoint held to an 8-slot worklist (pending links refill it)."""
+    for r, s in sorted(SUPPORTED_RS):
+        yield pytest.param(r, s, None, id=f"{r}-{s}")
+    yield pytest.param(2, 3, 8, id="2-3-w8")
+
+
+@pytest.mark.parametrize("r,s,width", mixed_cases())
+def test_update_batched_delta_mixed_ops(r, s, width, monkeypatch):
+    if width is not None:
+        monkeypatch.setattr(streaming, "_fixpoint_padded", partial(
+            streaming._fixpoint_padded, link_width=width))
     rng = np.random.default_rng(3)
     cfg = NucleusConfig(r=r, s=s, backend="dense", hierarchy="fused")
     g = GRAPHS["fig1"]()
